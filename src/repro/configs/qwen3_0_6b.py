@@ -1,10 +1,10 @@
-"""qwen3-0.6b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-0.6b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-0.6B; hf]"""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
     name="qwen3-0.6b",
     family="dense",
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-0.6B",
     n_layers=28,
     d_model=1024,
     n_heads=16,

@@ -82,8 +82,6 @@ class CompileCacheWarmer:
             cost = compiled.cost_analysis() or {}
         except Exception:
             pass
-        if isinstance(cost, (list, tuple)):   # older jax: list of dicts
-            cost = cost[0] if cost else {}
         entry = WarmEntry(compiled, t1 - t0, t2 - t1,
                           flops=cost.get("flops"),
                           bytes_accessed=cost.get("bytes accessed"))

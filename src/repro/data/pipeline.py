@@ -7,7 +7,6 @@ rescale: host h of H loads rows [h::H] of the global batch.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
@@ -77,17 +76,13 @@ class PackedBinReader:
 
 
 def make_batch_fn(cfg, shape, seed: int = 0, corpus: Optional[str] = None):
-    """Returns batch(step) for (arch cfg, ShapeConfig)."""
-    if corpus and os.path.exists(corpus):
+    """Returns batch(step) for (arch cfg, ShapeConfig): the packed corpus
+    at ``corpus`` when one is named (a missing file raises), else the
+    synthetic stream."""
+    if corpus:
         src = PackedBinReader(corpus, shape.seq_len, shape.global_batch,
                               seed=seed)
     else:
         src = SyntheticLM(cfg.vocab_size, shape.seq_len, shape.global_batch,
                           seed=seed)
-
-    def fn(step: int):
-        b = src.batch(step)
-        # labels shifted inside forward_loss; keep identical copies here
-        return b
-
-    return fn
+    return src.batch

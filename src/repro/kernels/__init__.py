@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
-Each kernel ships with a BlockSpec-tiled pl.pallas_call implementation, a
-jit'd wrapper (ops.py) and a pure-jnp oracle (ref.py); all are validated in
-interpret mode on CPU (tests/test_kernels.py) and target TPU v5e.
+Each kernel is a jit'd, BlockSpec-tiled pl.pallas_call that compiles for
+the TPU v5e, with a pure-jnp oracle in ref.py. The CPU tests
+(tests/test_kernels.py) pass ``interpret=True`` explicitly; nothing falls
+back to interpret mode on its own.
 """
-from . import flash_attention, ops, ref, rmsnorm, ssd_scan  # noqa: F401
+from . import flash_attention, ref, rmsnorm, ssd_scan  # noqa: F401

@@ -93,11 +93,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, block_q: int = 128,
-                    block_k: int = 512, interpret: bool = True):
+                    block_k: int = 512, interpret: bool = False):
     """q: [B, T, H, hd]; k/v: [B, S, KV, hd] -> [B, T, H, hd].
 
-    interpret=True runs the kernel body in Python on CPU (this container);
-    on TPU pass interpret=False for the compiled Mosaic kernel.
+    Compiles to a Mosaic kernel for the TPU. ``interpret=True`` runs the
+    kernel body in the Pallas interpreter instead (CPU tests only).
     """
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]

@@ -18,7 +18,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, gain, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: [..., d]; gain: [d]. Fused norm, fp32 internals."""
     shape = x.shape
     d = shape[-1]

@@ -78,7 +78,7 @@ def _slstm_kernel(wx_ref, r_ref, b_ref, hs_ref, c_ref, n_ref, m_ref, h_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def slstm_scan(wx, r, b, *, chunk: int = 64, interpret: bool = True):
+def slstm_scan(wx, r, b, *, chunk: int = 64, interpret: bool = False):
     """wx: [B, T, nh, 4dh] (input projection, gate-major per head);
     r: [nh, dh, 4dh]; b: [nh, 4dh]. Returns hs: [B, T, nh, dh].
 

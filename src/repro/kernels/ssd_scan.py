@@ -61,7 +61,7 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, a, B, C, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(x, a, B, C, *, chunk: int = 256, interpret: bool = False):
     """x: [b, T, H, P]; a: [b, T, H]; B/C: [b, T, H, N] (groups expanded).
 
     Returns y: [b, T, H, P]. Final state stays internal (training path);

@@ -218,15 +218,11 @@ def hlo_op_histogram(hlo: str) -> Dict[str, int]:
 
 
 def xla_cost_dict(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() normalized across jax versions: 0.4.x
-    returns a one-element list of dicts, newer jax a dict (or None)."""
+    """compiled.cost_analysis() as a dict ({} where the backend has none)."""
     try:
-        cost = compiled.cost_analysis() or {}
+        return compiled.cost_analysis() or {}
     except Exception:
         return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 # --------------------------------------------------------------------------

@@ -28,6 +28,7 @@ from repro.configs import get_config
 from repro.configs.base import SHAPES
 from repro.core.supervisor import SweepSupervisor
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import abstract_params, forward_loss, init_params
 from repro.optim import adamw_init, adamw_update
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--max-chips", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(get_config(args.arch).reduced(),
                               n_layers=2, param_dtype="float32",

@@ -5,8 +5,9 @@ Three implementations share one math definition (``ref`` oracle):
   * ``chunked`` — lax.map over query blocks with online softmax; flash-
                   attention memory profile in pure jnp. Default for training
                   and prefill (portable; honest HLO bytes for the roofline).
-  * ``pallas``  — repro.kernels.flash_attention (TPU target; interpret=True
-                  on CPU). Selected via cfg.attn_impl == "pallas".
+  * ``pallas``  — repro.kernels.flash_attention, compiled for the TPU.
+                  Selected via cfg.attn_impl == "pallas"; any other backend
+                  raises rather than running the Pallas interpreter.
 
 Decode attends one new token against a (possibly rolling) KV cache.
 """
@@ -218,12 +219,7 @@ def attend_context_parallel(q, k, v, cfg, mesh, *, causal: bool,
     shard_map boundary — where the GSPMD-auto formulation reinserted the
     partial-sum INSIDE the KV-block scan (8 psums of [B,H,blk,hd] per layer
     per microbatch; −187 GiB/step on qwen3-14b — EXPERIMENTS.md §Perf)."""
-    try:                                     # jax >= 0.6
-        from jax import shard_map
-        smap_kw = {"check_vma": False}
-    except ImportError:                      # jax 0.4.x/0.5.x
-        from jax.experimental.shard_map import shard_map
-        smap_kw = {"check_rep": False}
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel import ctx as pctx
     T = q.shape[1]
@@ -241,7 +237,7 @@ def attend_context_parallel(q, k, v, cfg, mesh, *, causal: bool,
                              P(dp, None, None, None),
                              P(dp, None, None, None)),
                    out_specs=P(dp, "model", None, None),
-                   **smap_kw)
+                   check_vma=False)
     return fn(q, k, v)
 
 
@@ -266,6 +262,10 @@ def attend(q, k, v, cfg, *, causal: bool = True, q_offset: int = 0,
                                  q_offset=q_offset)
         return pctx.constrain(out, dp, "model", None, None)
     if impl == "pallas":
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"attn_impl='pallas' compiles for the TPU; the backend is "
+                f"{jax.default_backend()!r}")
         from repro.kernels import flash_attention as fa
         return fa.flash_attention(q, k, v, causal=causal, window=window,
                                   q_offset=q_offset)

@@ -97,11 +97,12 @@ class Trainer:
     def run(self, num_steps: int) -> Dict[str, Any]:
         old1 = signal.signal(signal.SIGTERM, self._on_preempt)
         old2 = signal.signal(signal.SIGINT, self._on_preempt)
-        losses = []
+        losses, step_s = [], []
         t0 = time.monotonic()
         try:
             end = self.step + num_steps
             while self.step < end and not self._preempted:
+                t_step = time.monotonic()
                 batch = self.batch_fn(self.step)
                 batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
                 for attempt in range(self.tc.max_retries + 1):
@@ -115,11 +116,13 @@ class Trainer:
                             self._checkpoint(blocking=True)
                             raise
                         self.log(f"[trainer] step {self.step} failed "
-                                 f"({type(e).__name__}); retry {attempt+1}")
+                                 f"({type(e).__name__}: {e}); "
+                                 f"retry {attempt+1}")
                         time.sleep(0.1 * 2 ** attempt)
                 self.step += 1
-                loss = float(metrics["loss"])
+                loss = float(metrics["loss"])     # waits for the step
                 losses.append(loss)
+                step_s.append(time.monotonic() - t_step)
                 if self.step % self.tc.log_every == 0:
                     dt = time.monotonic() - t0
                     self.log(f"[trainer] step {self.step} loss {loss:.4f} "
@@ -133,5 +136,5 @@ class Trainer:
             signal.signal(signal.SIGTERM, old1)
             signal.signal(signal.SIGINT, old2)
             self.mgr.wait()
-        return {"losses": losses, "step": self.step,
+        return {"losses": losses, "step_s": step_s, "step": self.step,
                 "preempted": self._preempted}

@@ -73,3 +73,11 @@ def test_make_batch_fn_shapes():
     b = fn(0)
     assert b["tokens"].shape == (shape.global_batch, shape.seq_len)
     assert b["tokens"].max() < cfg.vocab_size
+
+
+def test_make_batch_fn_missing_corpus_raises(tmp_path):
+    """A named corpus that does not exist is an error, never synthetic data."""
+    cfg = get_config("qwen3_0_6b").reduced()
+    with pytest.raises(FileNotFoundError):
+        make_batch_fn(cfg, SHAPES["train_4k"],
+                      corpus=str(tmp_path / "missing.bin"))

@@ -21,12 +21,13 @@ from jax.sharding import NamedSharding
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.launch.hloparse import xla_cost_dict
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_step
 from repro.train.step import init_train_state, make_train_step
 from repro.data.pipeline import SyntheticLM
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 
 # 1) lower+compile one reduced cell per family through build_step
 for arch in ["qwen3_0_6b", "zamba2_2_7b", "mixtral_8x22b"]:

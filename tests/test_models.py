@@ -217,3 +217,11 @@ def test_label_mask_ignore_index():
     zero, m3 = forward_loss(p, cfg, all_masked)
     assert float(m3["ntokens"]) == 0
     assert jnp.isfinite(zero)                         # no div-by-zero NaN
+
+
+def test_pallas_attention_refuses_non_tpu_backend():
+    """attn_impl='pallas' never falls back to the Pallas interpreter."""
+    cfg = dataclasses.replace(_reduced("qwen3_0_6b"), attn_impl="pallas")
+    p = init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(RuntimeError, match="compiles for the TPU"):
+        forward_loss(p, cfg, tiny_batch(cfg, B=1, T=16))

@@ -17,23 +17,12 @@ from repro.models import abstract_params, init_cache
 from repro.parallel import (batch_specs, cache_specs, make_plan, param_specs,
                             token_spec)
 
-def _amesh(sizes, names):
-    """AbstractMesh across jax versions: >=0.5 takes (sizes, names); 0.4.x
-    takes one ((name, size), ...) tuple. Building it lazily here (instead of
-    at module level) also keeps a constructor change from killing collection
-    on single-device hosts."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 MESHES = [
-    _amesh((16, 16), ("data", "model")),                # production single
-    _amesh((2, 16, 16), ("pod", "data", "model")),      # production multi
-    _amesh((4, 8), ("data", "model")),                  # odd ratio
-    _amesh((1, 4), ("data", "model")),                  # TP-only
-    _amesh((8, 1), ("data", "model")),                  # DP-only
+    AbstractMesh((16, 16), ("data", "model")),          # production single
+    AbstractMesh((2, 16, 16), ("pod", "data", "model")),  # production multi
+    AbstractMesh((4, 8), ("data", "model")),            # odd ratio
+    AbstractMesh((1, 4), ("data", "model")),            # TP-only
+    AbstractMesh((8, 1), ("data", "model")),            # DP-only
 ]
 
 
