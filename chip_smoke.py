@@ -52,8 +52,14 @@ SERVE_ARGS = ["--requests", str(SERVE_REQUESTS), "--slots", str(SERVE_SLOTS),
 # gave 0.022-0.034 on the correct path and 0.72-1.1 with the decode
 # position off by one.
 SERVE_LOGIT_TOL = 0.1
+# a served token's full-forward logit may sit below that row's max by at
+# most this x max |logit| (a bf16 tie margin; see below)
+SERVE_ARGMAX_TOL = 0.1
 # four chips vs one: the same bf16 step under a different partitioning.
 FOUR_CHIP_LOSS_TOL = 2e-2
+# B=4 gives each data shard of the (2, 2) mesh the one-chip phase's B=2;
+# T=1024 because the one-device reference at B=4, T=2048 needs 15.4 GiB of
+# the chip's 15.75
 FOUR_CHIP_BATCH, FOUR_CHIP_SEQ, FOUR_CHIP_STEPS = 4, 1024, 3
 
 
@@ -109,40 +115,63 @@ def serve_phase(extra=()):
     info(f"serve: {len(eng.done)} requests in {time.monotonic() - t0:.2f} s "
          f"(compiles included)")
     cfg, params = eng.cfg, eng.params
-    check(len(eng.done) == SERVE_REQUESTS,
-          f"served {len(eng.done)}/{SERVE_REQUESTS}")
-    for r in eng.done.values():
-        check(len(r.tokens) == SERVE_MAX_NEW
+    done = [eng.done[rid] for rid in sorted(eng.done)]
+    n = SERVE_MAX_NEW
+    check(len(done) == SERVE_REQUESTS, f"served {len(done)}/{SERVE_REQUESTS}")
+    for r in done:
+        check(len(r.tokens) == n
               and all(0 <= t < cfg.vocab_size for t in r.tokens),
               f"request {r.rid} tokens {r.tokens}")
 
-    # prefill + decode against one full forward pass, on request 0
-    req = eng.done[0]
-    L, n = len(req.prompt), len(req.tokens)
-    toks = jnp.asarray(np.concatenate([req.prompt, req.tokens[:-1]]),
-                       jnp.int32)[None]
+    # one full forward over every served sequence, zero-padded at the end
+    # (attention is causal, so padding changes no earlier position); row j
+    # of ``full[i]`` is what predicts request i's j-th served token
+    seqs = [np.concatenate([r.prompt, r.tokens[:-1]]) for r in done]
+    toks = np.zeros((len(seqs), max(map(len, seqs))), np.int32)
+    for i, seq in enumerate(seqs):
+        toks[i, :len(seq)] = seq
+    full_fn = jax.jit(lambda p, t: lm_logits(p, cfg,
+                                             forward_hidden(p, cfg, t)[0]))
+    full_all = np.asarray(full_fn(params, jnp.asarray(toks)), np.float32)
+    full = [full_all[i, len(r.prompt) - 1:len(r.prompt) - 1 + n]
+            for i, r in enumerate(done)]
+
+    # what the engine served (slot insertion, per-slot cache_len, the
+    # batched decode): every token is the full forward's greedy pick, up to
+    # a bf16 tie margin
+    worst_gap = 0.0
+    for r, f in zip(done, full):
+        gap = (f.max(-1) - f[np.arange(n), r.tokens]) / np.abs(f).max()
+        check(float(gap.max()) <= SERVE_ARGMAX_TOL,
+              f"request {r.rid}: served {r.tokens}, full-forward argmax "
+              f"{f.argmax(-1).tolist()}, gaps {np.round(gap, 4).tolist()} "
+              f"x max |logit| > {SERVE_ARGMAX_TOL}")
+        worst_gap = max(worst_gap, float(gap.max()))
+
+    # the logits of prefill then decode, at batch 1, on request 0
+    req, ref = done[0], full[0]
+    L = len(req.prompt)
+    one = jnp.asarray(toks[:1, :L + n - 1])
     prefill_fn, decode_fn = make_prefill_fn(cfg), make_decode_fn(cfg)
-    logits, cache = prefill_fn(params, toks[:, :L])
+    logits, cache = prefill_fn(params, one[:, :L])
     rows = [logits[0]]
     for i in range(n - 1):
-        logits, cache = decode_fn(params, toks[:, L + i], cache,
+        logits, cache = decode_fn(params, one[:, L + i], cache,
                                   jnp.int32(L + i))
         rows.append(logits[0])
     inc = np.asarray(jnp.stack(rows), np.float32)
-    full = jax.jit(lambda p, t: lm_logits(p, cfg,
-                                          forward_hidden(p, cfg, t)[0]))
-    full = np.asarray(full(params, toks)[0, L - 1:], np.float32)
-    err, scale = float(np.abs(inc - full).max()), float(np.abs(full).max())
-    check(inc.shape == full.shape == (n, cfg.vocab_size),
-          f"logit shapes {inc.shape} vs {full.shape}")
+    err, scale = float(np.abs(inc - ref).max()), float(np.abs(ref).max())
+    check(inc.shape == ref.shape == (n, cfg.vocab_size),
+          f"logit shapes {inc.shape} vs {ref.shape}")
     check(bool(np.isfinite(inc).all()), "decode logits finite")
     check(err <= SERVE_LOGIT_TOL * scale,
           f"prefill+decode vs full forward: max |diff| {err:.4g} > "
           f"{SERVE_LOGIT_TOL} x max |logit| {scale:.4g}")
-    lens = [len(r.prompt) for r in eng.done.values()]
-    print(f"serve: ok, prompt lengths {lens}; prefill+decode vs forward "
-          f"max |diff| {err:.4g} (max |logit| {scale:.4g}, prompt {L}, "
-          f"{n} positions)", flush=True)
+    print(f"serve: ok, prompt lengths {[len(r.prompt) for r in done]}; "
+          f"served tokens vs full-forward argmax worst gap {worst_gap:.4g} "
+          f"x max |logit|; prefill+decode vs forward max |diff| {err:.4g} "
+          f"(max |logit| {scale:.4g}, prompt {L}, {n} positions)",
+          flush=True)
 
 
 def four_chip_phase(cfg=None):
@@ -203,8 +232,11 @@ def main(argv=None) -> None:
     devices = jax.devices()
     check(devices[0].platform == "tpu",
           f"no TPU: JAX found {devices[0].platform} devices")
-    check(len(devices) >= args.chips,
-          f"{args.chips} chips asked, {len(devices)} found")
+    # the train CLI builds its mesh over every visible device, and the
+    # result's count is what JAX reports, so the two must agree
+    check(len(devices) == args.chips,
+          f"--chips {args.chips} needs exactly {args.chips} visible "
+          f"devices; JAX found {len(devices)}")
 
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
     from repro.launch.compile_cache import enable_compile_cache

@@ -1,13 +1,14 @@
 """Training launcher CLI.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
-        --steps 100 [--reduced] [--ckpt-dir /tmp/ckpt]
+        --ckpt-dir CKPT --steps 100 [--reduced]
 
 Trains the config at its published widths on a data-parallel mesh over
 every device that exists; ``--reduced`` swaps in the tiny CPU smoke config.
 The Trainer provides async checkpointing, preemption handling (SIGTERM ->
 checkpoint -> exit), bounded step retry, and resume from the newest
-checkpoint in ``--ckpt-dir`` (see repro.train.trainer).
+checkpoint in ``--ckpt-dir`` (see repro.train.trainer), which is therefore
+required: a run resumes only from the directory it names.
 """
 from __future__ import annotations
 
@@ -35,7 +36,9 @@ def parse_args(argv=None):
                     help="global batch; default 2 per device (8 with "
                          "--reduced)")
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", required=True,
+                    help="checkpoints go here; the run resumes from the "
+                         "newest one found")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--data", default=None, help="packed .bin corpus path")
     return ap.parse_args(argv)

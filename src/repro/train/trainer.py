@@ -28,7 +28,7 @@ from repro.train.step import init_train_state, make_train_step
 
 @dataclass
 class TrainerConfig:
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str            # resumed from if it holds a checkpoint
     ckpt_every: int = 50
     keep: int = 3
     max_retries: int = 3

@@ -32,14 +32,21 @@ def _run(args, **env):
     ([], get_config("qwen3-0.6b")),
     (["--reduced"], get_config("qwen3-0.6b").reduced()),
 ], ids=["full", "reduced"])
-def test_train_cli_resolves_config_on_one_device(extra, want):
+def test_train_cli_resolves_config_on_one_device(extra, want, tmp_path):
     """One device no longer implies the toy config: only --reduced does."""
-    cfg, mesh, shape = train.resolve(
-        train.parse_args(["--arch", "qwen3-0.6b", *extra]))
+    cfg, mesh, shape = train.resolve(train.parse_args(
+        ["--arch", "qwen3-0.6b", "--ckpt-dir", str(tmp_path), *extra]))
     assert cfg == want
     assert dict(mesh.shape) == {"data": 1, "model": 1}
     assert (shape.seq_len, shape.global_batch) == (
         (64, 8) if extra else (2048, 2))
+
+
+def test_train_cli_requires_ckpt_dir():
+    """The trainer resumes from whatever its checkpoint directory holds, so
+    the CLI has no shared default directory to resume from."""
+    with pytest.raises(SystemExit):
+        train.parse_args(["--arch", "qwen3-0.6b"])
 
 
 @pytest.mark.parametrize("external", [None, "elsewhere/cache"])
