@@ -48,12 +48,17 @@ SERVE_ARGS = ["--requests", str(SERVE_REQUESTS), "--slots", str(SERVE_SLOTS),
               "--max-seq", str(SERVE_MAX_SEQ), "--max-new", str(SERVE_MAX_NEW)]
 # |prefill+decode logits - full-forward logits| <= TOL * max |full logits|.
 # Both paths run bf16 weights and a bf16 KV cache through 28 layers and sum
-# in different orders. On the CPU, a 28-layer bf16 model at reduced widths
-# gave 0.022-0.034 on the correct path and 0.72-1.1 with the decode
-# position off by one.
+# in different orders. Readings on a TPU v5e at full width: 0.042-0.050 on
+# the correct path, 0.64 with the decode position off by one. Matmul
+# precision "highest" gives the same readings as the default; the same
+# 28-layer model at reduced widths reads 0.014 on the TPU and 0.005 on the
+# CPU, so the chip's larger error comes from its bf16 arithmetic and from
+# width.
 SERVE_LOGIT_TOL = 0.1
 # a served token's full-forward logit may sit below that row's max by at
-# most this x max |logit| (a bf16 tie margin; see below)
+# most this x max |logit| (a bf16 tie margin). Readings on a TPU v5e at full
+# width: 0-0.015 on the correct path, 0.32 with the decode position off by
+# one, 1.16-1.25 for tokens picked from another request's context.
 SERVE_ARGMAX_TOL = 0.1
 # four chips vs one: the same bf16 step under a different partitioning.
 FOUR_CHIP_LOSS_TOL = 2e-2
