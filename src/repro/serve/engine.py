@@ -7,13 +7,16 @@ One jitted decode program serves B slots; requests stream in/out of slots:
               step for every active slot; finished sequences free slots.
 
 Per-slot cache lengths (vectorized cache_len) make heterogeneous prompt
-lengths exact, not padded-approximate. Prompt lengths are bucketed to
-powers of two so prefill compiles O(log max_len) variants (the compile
+lengths exact, not padded-approximate. Prefill runs at each prompt's exact
+length, so every distinct length compiles a program of its own (the compile
 cache is prepositioned by repro.core.preposition — the paper's T4).
+
+Each step of admit and tick is a ``jax.profiler.TraceAnnotation`` named
+``repro.serve.*``, with its counters as arguments, so a profiler trace of a
+running engine places them beside the device's work (README, "Tracing").
 """
 from __future__ import annotations
 
-import math
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -21,6 +24,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import decode_step, init_cache, prefill
@@ -28,16 +32,16 @@ from repro.models import decode_step, init_cache, prefill
 
 def make_prefill_fn(cfg: ArchConfig):
     @jax.jit
-    def fn(params, tokens):
+    def serve_prefill(params, tokens):
         return prefill(params, cfg, tokens)
-    return fn
+    return serve_prefill
 
 
 def make_decode_fn(cfg: ArchConfig):
     @jax.jit
-    def fn(params, token, cache, cache_len):
+    def serve_decode(params, token, cache, cache_len):
         return decode_step(params, cfg, token, cache, cache_len)
-    return fn
+    return serve_decode
 
 
 @dataclass
@@ -50,10 +54,6 @@ class Request:
     submitted_at: float = 0.0
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
-
-
-def _bucket(n: int) -> int:
-    return 1 << max(4, math.ceil(math.log2(max(n, 1))))
 
 
 def _insert_slot(cache, slot_cache, idx: int):
@@ -81,7 +81,7 @@ class ServeEngine:
         self.next_token = np.zeros((slots,), np.int32)
         self._rid = 0
         self._decode = make_decode_fn(cfg)
-        self._prefills: Dict[int, Any] = {}   # per-bucket jitted prefill
+        self._prefills: Dict[int, Any] = {}   # per-length jitted prefill
         self.stats = {"decode_steps": 0, "prefills": 0}
 
     # ------------------------------------------------------------------
@@ -92,16 +92,16 @@ class ServeEngine:
                                   max_new, eos, submitted_at=time.monotonic()))
         return rid
 
-    def _prefill_fn(self, bucket: int):
-        if bucket not in self._prefills:
+    def _prefill_fn(self, length: int):
+        if length not in self._prefills:
             cfg = self.cfg
 
             @jax.jit
-            def fn(params, tokens):
+            def serve_prefill(params, tokens):
                 return prefill(params, cfg, tokens,
                                pad=self.max_seq - tokens.shape[1])
-            self._prefills[bucket] = fn
-        return self._prefills[bucket]
+            self._prefills[length] = serve_prefill
+        return self._prefills[length]
 
     def _admit(self):
         for slot in range(self.slots):
@@ -109,52 +109,68 @@ class ServeEngine:
                 continue
             req = self.queue.pop(0)
             L = len(req.prompt)
-            # exact-length prefill: one compiled program per distinct prompt
-            # length; the compile cache is prepositioned ahead of the
-            # interactive session (repro.core.preposition, paper T4).
-            toks = req.prompt[None, :]
-            logits, c1 = self._prefill_fn(L)(self.params, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[0]))
-            req.tokens.append(nxt)
-            req.first_token_at = time.monotonic()
-            self.stats["prefills"] += 1
-            if nxt == req.eos or len(req.tokens) >= req.max_new:
-                # finished at the first token: never occupies a slot
-                req.done_at = time.monotonic()
-                self.done[req.rid] = req
-                continue
-            self.cache = _insert_slot(self.cache, c1, slot)
-            self.active[slot] = req
-            self.cache_len[slot] = L
-            self.next_token[slot] = nxt
+            wait_us = int(1e6 * (time.monotonic() - req.submitted_at))
+            with TraceAnnotation("repro.serve.admit", rid=req.rid, length=L,
+                                 wait_us=wait_us):
+                with TraceAnnotation("repro.serve.prefill", rid=req.rid):
+                    # exact-length prefill: one compiled program per
+                    # distinct prompt length; the compile cache is
+                    # prepositioned ahead of the interactive session
+                    # (repro.core.preposition, paper T4).
+                    toks = req.prompt[None, :]
+                    logits, c1 = self._prefill_fn(L)(self.params,
+                                                     jnp.asarray(toks))
+                    nxt = int(jnp.argmax(logits[0]))
+                req.tokens.append(nxt)
+                req.first_token_at = time.monotonic()
+                self.stats["prefills"] += 1
+                if nxt == req.eos or len(req.tokens) >= req.max_new:
+                    # finished at the first token: never occupies a slot
+                    req.done_at = time.monotonic()
+                    self.done[req.rid] = req
+                    continue
+                with TraceAnnotation("repro.serve.insert", rid=req.rid,
+                                     slot=slot):
+                    self.cache = _insert_slot(self.cache, c1, slot)
+                self.active[slot] = req
+                self.cache_len[slot] = L
+                self.next_token[slot] = nxt
 
     # ------------------------------------------------------------------
     def tick(self):
         """Admit + one decode step across all active slots."""
         self._admit()
-        if not any(r is not None for r in self.active):
+        active = sum(r is not None for r in self.active)
+        if not active:
             return False
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(self.next_token), self.cache,
-            jnp.asarray(self.cache_len))
+        with TraceAnnotation("repro.serve.decode", active=active,
+                             slots=self.slots):
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(self.next_token), self.cache,
+                jnp.asarray(self.cache_len))
         self.stats["decode_steps"] += 1
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        else:
-            self.key, sub = jax.random.split(self.key)
-            nxt = np.asarray(jax.random.categorical(sub, logits), np.int32)
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.cache_len[slot] += 1
-            tok = int(nxt[slot])
-            req.tokens.append(tok)
-            self.next_token[slot] = tok
-            if tok == req.eos or len(req.tokens) >= req.max_new:
-                req.done_at = time.monotonic()
-                self.done[req.rid] = req
-                self.active[slot] = None
-                self.cache_len[slot] = 0
+        with TraceAnnotation("repro.serve.sample") as span:
+            if self.greedy:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            else:
+                self.key, sub = jax.random.split(self.key)
+                nxt = np.asarray(jax.random.categorical(sub, logits),
+                                 np.int32)
+            finished = 0
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                self.cache_len[slot] += 1
+                tok = int(nxt[slot])
+                req.tokens.append(tok)
+                self.next_token[slot] = tok
+                if tok == req.eos or len(req.tokens) >= req.max_new:
+                    req.done_at = time.monotonic()
+                    self.done[req.rid] = req
+                    self.active[slot] = None
+                    self.cache_len[slot] = 0
+                    finished += 1
+            span.set_metadata(finished=finished)
         return True
 
     def run(self, max_ticks: int = 10_000):

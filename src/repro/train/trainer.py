@@ -9,6 +9,11 @@ Production behaviours, testable single-host:
     re-dispatch lives in repro.core)
   * deterministic data by step index -> no data loss/duplication across
     restarts.
+
+Each part of a step (batch, dispatch, sync) and each checkpoint save is a
+``jax.profiler.TraceAnnotation`` named ``repro.train.*`` with the step as an
+argument, so a profiler trace of a running trainer places them beside the
+device's work (README, "Tracing").
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.ckpt import CheckpointManager, latest_step, restore
@@ -86,9 +92,12 @@ class Trainer:
 
     def _checkpoint(self, blocking=False):
         state = {"params": self.params, "opt": self.opt_state}
-        self.mgr.save_async(self.step, state, meta={"arch": self.cfg.name})
-        if blocking:
-            self.mgr.wait()
+        with TraceAnnotation("repro.train.checkpoint", step=self.step,
+                             blocking=int(blocking)):
+            self.mgr.save_async(self.step, state,
+                                meta={"arch": self.cfg.name})
+            if blocking:
+                self.mgr.wait()
 
     def _on_preempt(self, signum, frame):
         self._preempted = True
@@ -103,30 +112,36 @@ class Trainer:
             end = self.step + num_steps
             while self.step < end and not self._preempted:
                 t_step = time.monotonic()
-                batch = self.batch_fn(self.step)
-                batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-                for attempt in range(self.tc.max_retries + 1):
-                    try:
-                        self.params, self.opt_state, metrics = self.step_fn(
-                            self.params, self.opt_state, batch,
-                            jax.numpy.int32(self.step))
-                        break
-                    except Exception as e:     # transient failure -> retry
-                        if attempt == self.tc.max_retries:
-                            self._checkpoint(blocking=True)
-                            raise
-                        self.log(f"[trainer] step {self.step} failed "
-                                 f"({type(e).__name__}: {e}); "
-                                 f"retry {attempt+1}")
-                        time.sleep(0.1 * 2 ** attempt)
+                with TraceAnnotation("repro.train.batch", step=self.step):
+                    batch = self.batch_fn(self.step)
+                    batch = {k: jax.numpy.asarray(v)
+                             for k, v in batch.items()}
+                with TraceAnnotation("repro.train.dispatch",
+                                     step=self.step) as span:
+                    for attempt in range(self.tc.max_retries + 1):
+                        try:
+                            self.params, self.opt_state, metrics = \
+                                self.step_fn(self.params, self.opt_state,
+                                             batch, jax.numpy.int32(self.step))
+                            break
+                        except Exception as e:  # transient failure -> retry
+                            if attempt == self.tc.max_retries:
+                                self._checkpoint(blocking=True)
+                                raise
+                            self.log(f"[trainer] step {self.step} failed "
+                                     f"({type(e).__name__}: {e}); "
+                                     f"retry {attempt+1}")
+                            time.sleep(0.1 * 2 ** attempt)
+                    span.set_metadata(attempt=attempt)
                 self.step += 1
-                loss = float(metrics["loss"])     # waits for the step
+                with TraceAnnotation("repro.train.sync", step=self.step - 1):
+                    loss = float(metrics["loss"])     # waits for the step
                 losses.append(loss)
                 step_s.append(time.monotonic() - t_step)
                 if self.step % self.tc.log_every == 0:
                     dt = time.monotonic() - t0
                     self.log(f"[trainer] step {self.step} loss {loss:.4f} "
-                             f"({self.step * 0 + dt:.1f}s)")
+                             f"({dt:.1f}s)")
                 if self.step % self.tc.ckpt_every == 0:
                     self._checkpoint()
             if self._preempted:
