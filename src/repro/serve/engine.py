@@ -3,7 +3,8 @@
 One jitted decode program serves B slots; requests stream in/out of slots:
   submit()  — queue a prompt
   tick()    — admit queued requests into free slots (per-request prefill,
-              cache scatter at the slot index), then one batched decode
+              then ``serve_insert`` writes its cache into the slot in
+              place, donating the batched cache), then one batched decode
               step for every active slot; finished sequences free slots.
 
 Per-slot cache lengths (vectorized cache_len) make heterogeneous prompt
@@ -17,6 +18,7 @@ running engine places them beside the device's work (README, "Tracing").
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -38,6 +40,10 @@ def make_prefill_fn(cfg: ArchConfig):
 
 
 def make_decode_fn(cfg: ArchConfig):
+    # No donation yet: the decode's layer scan reads the stacked cache as
+    # ``xs`` and writes a new stacked ``ys``, which cannot share a buffer
+    # with the ``xs`` it is still reading, so a donated cache would cost a
+    # whole-cache copy at the loop boundary instead of saving one.
     @jax.jit
     def serve_decode(params, token, cache, cache_len):
         return decode_step(params, cfg, token, cache, cache_len)
@@ -56,13 +62,19 @@ class Request:
     done_at: Optional[float] = None
 
 
-def _insert_slot(cache, slot_cache, idx: int):
-    """Scatter a single-request cache (B=1) into slot ``idx`` of the batched
-    cache. Every leaf has batch at dim 1 ([L, B, ...]) by construction."""
-    def ins(big, one):
-        return jax.lax.dynamic_update_slice_in_dim(big, one.astype(big.dtype),
-                                                   idx, axis=1)
-    return jax.tree_util.tree_map(ins, cache, slot_cache)
+def make_insert_fn():
+    """Writes a single-request cache (B=1) into slot ``slot`` (a traced
+    int32) of the batched cache, whose buffers it donates, so each leaf is
+    updated in place. Every leaf has batch at dim 1 ([L, B, ...]) by
+    construction; the prefill's cache has the batched cache's shape but for
+    the batch, so one program serves every slot and prompt length."""
+    @functools.partial(jax.jit, donate_argnums=0)
+    def serve_insert(cache, slot_cache, slot):
+        def ins(big, one):
+            return jax.lax.dynamic_update_slice_in_dim(
+                big, one.astype(big.dtype), slot, axis=1)
+        return jax.tree_util.tree_map(ins, cache, slot_cache)
+    return serve_insert
 
 
 class ServeEngine:
@@ -81,8 +93,10 @@ class ServeEngine:
         self.next_token = np.zeros((slots,), np.int32)
         self._rid = 0
         self._decode = make_decode_fn(cfg)
+        self._insert = make_insert_fn()
         self._prefills: Dict[int, Any] = {}   # per-length jitted prefill
-        self.stats = {"decode_steps": 0, "prefills": 0}
+        self.stats = {"decode_steps": 0, "prefills": 0, "inserts": 0,
+                      "inserts_in_place": 0}
 
     # ------------------------------------------------------------------
     def submit(self, prompt, max_new: int = 32, eos: int = -1) -> int:
@@ -130,8 +144,15 @@ class ServeEngine:
                     self.done[req.rid] = req
                     continue
                 with TraceAnnotation("repro.serve.insert", rid=req.rid,
-                                     slot=slot):
-                    self.cache = _insert_slot(self.cache, c1, slot)
+                                     slot=slot) as span:
+                    old = jax.tree_util.tree_leaves(self.cache)
+                    self.cache = self._insert(self.cache, c1, np.int32(slot))
+                    # the donation took where every old buffer is gone
+                    in_place = int(all(x.is_deleted() for x in old))
+                    del old
+                    span.set_metadata(in_place=in_place)
+                self.stats["inserts"] += 1
+                self.stats["inserts_in_place"] += in_place
                 self.active[slot] = req
                 self.cache_len[slot] = L
                 self.next_token[slot] = nxt
