@@ -59,7 +59,7 @@ def setup(run, exact=True):
     from repro.serve.engine import ServeEngine
 
     c, tr = run.cell.config, run.cell.traffic
-    params = weights.to_program(
+    params = spec.arch(c).to_program(
         weights.maker(c)(weights.seed_key(run.seed)))
     eng = ServeEngine(spec.arch_config(c, exact), params, slots=tr["slots"],
                       max_seq=tr["max_seq"])

@@ -46,28 +46,28 @@ def drive(run, seconds, tracer, compiles, t_start, exact=True):
             ckpt_dir=ckpt, ckpt_every=10**9, log_every=10**9,
             peak_lr=tr["peak_lr"], warmup=tr["warmup"],
             total_steps=tr["total_steps"]), log=lambda s: None)
-        key = weights.seed_key(run.seed)
-        shard = weights.from_program(
+        arch, key = spec.arch(c), weights.seed_key(run.seed)
+        shard = arch.from_program(
             jax.tree.map(lambda a: a.sharding, trainer.params))
         _delete(trainer.params)
         make = weights.maker(c, shard)
-        trainer.params = weights.to_program(make(key))
+        trainer.params = arch.to_program(make(key))
 
         # the steps the reference follows
         losses = trainer.run(1)["losses"]
         b1 = tr["optimizer"]["b1"]
         grad = jax.jit(lambda m: weights.leaf_norms(
-            {n: x.astype(jnp.float32) / (1 - b1) for n, x in m.items()}))(
-            weights.from_program(trainer.opt_state["m"]))
+            {n: x.astype(jnp.float32) / (1 - b1) for n, x in m.items()},
+            c))(arch.from_program(trainer.opt_state["m"]))
         losses += trainer.run(k - 1)["losses"]
         change = jax.jit(lambda p, key: weights.leaf_norms(
             {n: p[n].astype(jnp.float32) - w.astype(jnp.float32)
-             for n, w in weights.make(c, key).items()}))(
-            weights.from_program(trainer.params), key)
+             for n, w in weights.make(c, key).items()}, c))(
+            arch.from_program(trainer.params), key)
         readings = {"losses": losses,
-                    "grad_norms": weights.flat_norms(jax.device_get(grad)),
+                    "grad_norms": weights.flat_norms(jax.device_get(grad), c),
                     "change_norms": weights.flat_norms(
-                        jax.device_get(change))}
+                        jax.device_get(change), c)}
 
         step_flops = flops.train_step(c, B, T)
         compiles.active = True
@@ -135,5 +135,5 @@ def reference_readings(run, mode="f32", transform=None, lr_scale=1.0):
     with jax.default_matmul_precision("highest"):
         ref = reference.train(w0, c, batches, tr, mode)
     return {"losses": ref["losses"],
-            "grad_norms": weights.flat_norms(ref["grad_norms"]),
-            "change_norms": weights.flat_norms(ref["change_norms"])}
+            "grad_norms": weights.flat_norms(ref["grad_norms"], c),
+            "change_norms": weights.flat_norms(ref["change_norms"], c)}

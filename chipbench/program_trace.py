@@ -10,15 +10,17 @@ what ``trace_reduce.load`` reads; ``reduce`` adds, inside the traced window:
 - ``program_spans``: [name without ``repro.``, start ns, end ns, {argument:
   value}] of every program span that lies inside the window;
 - ``modules``: program -> [runs, device seconds], each clipped to the
-  window and the mean over the devices;
+  window and the mean over the devices: a run cut by an edge of the window
+  counts by the share of it inside, so seconds / runs is the mean run;
 - ``program_gaps``: the device's idle time split by the innermost span of
   either kind over it, program (``serve.decode``) or harness (``tick``),
   so that a harness name marks idle time that no program span covers. Its
   parts add up to the same idle total as ``idle_gaps``.
 
 ``numbers`` turns these into the per-layer quantities of PERF.md section 3.
-``trace_reduce.py`` is left as it is, so the accepted metrics read what
-they read.
+``run.py``'s traced runs reduce their trace with ``reduce`` (``run.trace``),
+so a metric reader can read any of them; ``trace_reduce.reduce``'s keys are
+there as before, and the breakdown keeps to them.
 
 Run as a script, it reduces a recorded trace:
 
@@ -38,7 +40,6 @@ both splits, and ``numbers``.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import re
 import shutil
@@ -81,7 +82,7 @@ def module_name(text: str) -> str:
 def load(path: str) -> ProgramTrace:
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
-    pt = ProgramTrace(trace_reduce.load(path))
+    pt = ProgramTrace(trace_reduce.from_profile(pd))
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
             for line in plane.lines:
@@ -158,8 +159,9 @@ def reduce(pt: ProgramTrace, win: Optional[Tuple[float, float]] = None
     for mods in pt.modules.values():
         for name, s, e in mods:
             if e > lo and s < hi:
-                runs[name][0] += 1 / n
-                runs[name][1] += (min(e, hi) - max(s, lo)) * ns / n
+                inside = min(e, hi) - max(s, lo)
+                runs[name][0] += (inside / (e - s) if e > s else 1) / n
+                runs[name][1] += inside * ns / n
     out["program_spans"] = [[name, s, e, args]
                             for name, s, e, args in sorted(
                                 pt.spans, key=lambda sp: sp[1])
@@ -226,8 +228,8 @@ def summary(red: Optional[dict]) -> dict:
 def kept_tracer(store: dict, keep: Optional[str], delay_s: float,
                 limit_s: float):
     """run.py's Tracer, except that it starts ``delay_s`` into the window,
-    records ``limit_s``, copies the trace to ``keep``, and reduces it with
-    ``reduce`` above into ``store["trace"]`` (run.py deletes its trace once
+    records ``limit_s``, copies the trace to ``keep``, and keeps its
+    reduction in ``store["trace"]`` (run.py deletes its trace once
     reduced)."""
     from chipbench import run as bench_run
 
@@ -247,21 +249,12 @@ def kept_tracer(store: dict, keep: Optional[str], delay_s: float,
                 super().start()
             super().after()
 
-        def reduce(self):
-            if self.dir is None:
-                return None
-            try:
-                path = glob.glob(f"{self.dir}/**/*.xplane.pb",
-                                 recursive=True)
-                if not path:
-                    return None
-                if keep:
-                    Path(keep).mkdir(parents=True, exist_ok=True)
-                    shutil.copy(path[0], Path(keep) / Path(path[0]).name)
-                store["trace"] = reduce(load(path[0]))
-                return store["trace"]
-            finally:
-                shutil.rmtree(self.dir, ignore_errors=True)
+        def reduce_file(self, path):
+            if keep:
+                Path(keep).mkdir(parents=True, exist_ok=True)
+                shutil.copy(path, Path(keep) / Path(path).name)
+            store["trace"] = super().reduce_file(path)
+            return store["trace"]
 
     return KeptTracer
 

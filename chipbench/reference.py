@@ -1,12 +1,14 @@
-"""Plain float32 reference of the dense Qwen2 / Qwen3 decoder.
+"""Plain float32 reference of a decoder language model.
 
-Written from the published description (Qwen2 technical report,
-arXiv:2407.10671; the Qwen3 model card and config.json), not from the
-program: it imports nothing of ``repro``. Covers RMSNorm, GQA, rotary
-embeddings on halves (rotate_half), Qwen3's per-head RMSNorm of q and k,
-Qwen2's q/k/v biases, SwiGLU, tied embeddings, the next-token loss and
-AdamW with global-norm clipping. Every matrix product runs at
-``Precision.HIGHEST``, so the chip computes it in full float32.
+Written from the published descriptions, not from the program: it imports
+nothing of ``repro``. The decoder layers of each family are in its arch
+module (``chipbench/archs/<bench_arch>.py``, ``hidden``), built from the
+pieces here that arch modules import: ``rms`` (RMSNorm), ``rope`` (rotary
+embeddings on halves, rotate_half), ``attention`` (causal, heads grouped)
+and ``einsum`` (a product in the reference's precision). Here too: the LM
+head (tied or not), the next-token loss and AdamW with global-norm
+clipping. Every matrix product runs at ``Precision.HIGHEST``, so the chip
+computes it in full float32.
 
 ``mode="fp8"`` is the control: the same arithmetic with each product's
 operands rounded to float8 under a per-tensor scale (e4m3 forward, e5m2
@@ -22,14 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
 from chipbench.weights import leaf_norms, rounded
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512        # query rows per attention block
 LOSS_BLOCK = 512     # positions per block of the LM head and loss
-MATRICES = ("embed", "lm_head", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-            "w_down")
 
 
 def _round(x, exponent_bits, mantissa_bits, top):
@@ -59,18 +60,18 @@ def _fp8_bwd(_, g):
 _fp8.defvjp(_fp8_fwd, _fp8_bwd)
 
 
-def _einsum(mode: str, spec: str, a, b):
+def einsum(mode: str, subscripts: str, a, b):
     if mode == "fp8":
         a, b = _fp8(a), _fp8(b)
-    return jnp.einsum(spec, a, b, precision=HIGHEST,
+    return jnp.einsum(subscripts, a, b, precision=HIGHEST,
                       preferred_element_type=F32)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [B, T, n, hd]; rotates the two halves of each head."""
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=F32) * 2.0 / x.shape[-1])
@@ -80,8 +81,9 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, mode):
-    """Causal GQA. q [B, T, H, hd], k/v [B, T, KV, hd] -> [B, T, H, hd]."""
+def attention(q, k, v, mode):
+    """Causal attention, heads grouped: q [B, T, H, hd], k [B, T, KV, hd],
+    v [B, T, KV, vd] -> [B, T, H, vd], scores scaled by 1/sqrt(hd)."""
     B, T, H, hd = q.shape
     rep = H // k.shape[2]
     k = jnp.repeat(k, rep, axis=2)          # q head h reads kv head h // rep
@@ -92,62 +94,26 @@ def _attention(q, k, v, mode):
 
     def one(args):
         qi, i = args
-        s = _einsum(mode, "bqhd,bkhd->bhqk", qi, k) / np.sqrt(hd)
+        s = einsum(mode, "bqhd,bkhd->bhqk", qi, k) / np.sqrt(hd)
         ok = jnp.arange(T)[None, :] <= (i * blk + jnp.arange(blk))[:, None]
         s = jnp.where(ok, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _einsum(mode, "bhqk,bkhd->bqhd", p, v)
+        return einsum(mode, "bhqk,bkhd->bqhd", p, v)
 
     out = jax.lax.map(one, (qb, jnp.arange(nb)))
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, T, H, hd)
-
-
-def _layer(c, mode, x, lw, pos):
-    lw = {k: a.astype(F32) for k, a in lw.items()}
-    eps, hd = c["rms_norm_eps"], c["head_dim"]
-    B, T, _ = x.shape
-    h = _rms(x, lw["ln1"], eps)
-    q = _einsum(mode, "btd,de->bte", h, lw["wq"])
-    k = _einsum(mode, "btd,de->bte", h, lw["wk"])
-    v = _einsum(mode, "btd,de->bte", h, lw["wv"])
-    if c["attention_bias"]:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    q = q.reshape(B, T, -1, hd)
-    k = k.reshape(B, T, -1, hd)
-    v = v.reshape(B, T, -1, hd)
-    if c["qk_norm"]:
-        q, k = _rms(q, lw["q_norm"], eps), _rms(k, lw["k_norm"], eps)
-    q, k = _rope(q, pos, c["rope_theta"]), _rope(k, pos, c["rope_theta"])
-    a = _attention(q, k, v, mode).reshape(B, T, -1)
-    x = x + _einsum(mode, "bte,ed->btd", a, lw["wo"])
-    h = _rms(x, lw["ln2"], eps)
-    g = _einsum(mode, "btd,df->btf", h, lw["w_gate"])
-    u = _einsum(mode, "btd,df->btf", h, lw["w_up"])
-    return x + _einsum(mode, "btf,fd->btd", jax.nn.silu(g) * u, lw["w_down"])
-
-
-def hidden(w: Dict[str, Any], c: Dict[str, Any], tokens, mode="f32"):
-    """Final-normed hidden states [B, T, d] for token ids [B, T]."""
-    B, T = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-    x = jnp.take(w["embed"].astype(F32), tokens, axis=0)
-    layers = {k: w[k] for k in w if k not in ("embed", "lm_head",
-                                              "final_norm")}
-    step = jax.checkpoint(functools.partial(_layer, c, mode))
-    x, _ = jax.lax.scan(lambda x, lw: (step(x, lw, pos), None), x, layers)
-    return _rms(x, w["final_norm"].astype(F32), c["rms_norm_eps"])
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, T, H, v.shape[-1])
 
 
 def head(w, h, mode="f32"):
     """Logits for hidden rows h [..., d]."""
     table = w["embed"] if "lm_head" not in w else w["lm_head"]
-    return _einsum(mode, "...d,vd->...v", h, table.astype(F32))
+    return einsum(mode, "...d,vd->...v", h, table.astype(F32))
 
 
 def loss(w, c, tokens, mode="f32"):
     """Mean next-token NLL: position t predicts token t+1."""
     B, T = tokens.shape
-    h = hidden(w, c, tokens, mode)
+    h = spec.arch(c).hidden(w, c, tokens, mode)
     target = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
     valid = (jnp.arange(T) < T - 1).astype(F32)
     blk = min(LOSS_BLOCK, T)
@@ -183,6 +149,7 @@ def _adamw_step(c, opt, mode, state, tokens, lr, count):
     configuration's parameter dtype."""
     p, m, v = state
     lval, g = jax.value_and_grad(loss)(p, c, tokens, mode)
+    matrices = spec.arch(c).MATRICES
     gn = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in g.values()))
     g = {k: x * jnp.minimum(1.0, opt["max_grad_norm"] / jnp.maximum(gn, 1e-9))
          for k, x in g.items()}
@@ -194,10 +161,10 @@ def _adamw_step(c, opt, mode, state, tokens, lr, count):
         new_m[k] = b1 * m[k] + (1 - b1) * g[k]
         new_v[k] = b2 * v[k] + (1 - b2) * jnp.square(g[k])
         upd = (new_m[k] / c1) / (jnp.sqrt(new_v[k] / c2) + opt["eps"])
-        if k in MATRICES:
+        if k in matrices:
             upd = upd + opt["weight_decay"] * p[k]
         new_p[k] = rounded(p[k] - lr * upd, store)
-    return (new_p, new_m, new_v), lval, leaf_norms(g)
+    return (new_p, new_m, new_v), lval, leaf_norms(g, c)
 
 
 def train(w0: Dict[str, Any], c: Dict[str, Any], batches: Sequence,
@@ -224,20 +191,16 @@ def train(w0: Dict[str, Any], c: Dict[str, Any], batches: Sequence,
         if grad_norms is None:
             grad_norms = jax.device_get(gnorm)
     change = jax.jit(lambda p, w: leaf_norms(
-        {k: p[k] - w[k].astype(F32) for k in p}))(state[0], w0)
+        {k: p[k] - w[k].astype(F32) for k in p}, c))(state[0], w0)
     return {"losses": losses, "grad_norms": grad_norms,
             "change_norms": jax.device_get(change)}
 
 
-REF_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "intermediate_size",
-            "vocab_size", "rms_norm_eps", "rope_theta", "tie_word_embeddings",
-            "attention_bias", "qk_norm", "param_dtype")
-
-
 def items(c: Dict[str, Any]) -> tuple:
-    """The sizes the reference reads, hashable for a static jit argument."""
-    return tuple((k, c[k]) for k in REF_KEYS)
+    """The arch and the sizes its reference reads (its ``REF_KEYS``),
+    hashable for a static jit argument."""
+    return (("bench_arch", c["bench_arch"]),) + tuple(
+        (k, c[k]) for k in spec.arch(c).REF_KEYS)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 5))
@@ -249,7 +212,7 @@ def served_gaps(w, c_items, tokens, pos, served, control: bool):
     ``control``, also the gap of the token that the fp8 control puts
     first."""
     c = dict(c_items)
-    ref = head(w, hidden(w, c, tokens)[0][pos])
+    ref = head(w, spec.arch(c).hidden(w, c, tokens)[0][pos])
     best, spread = jnp.max(ref, -1), jnp.std(ref, -1)
 
     def gap(tok):
@@ -258,5 +221,6 @@ def served_gaps(w, c_items, tokens, pos, served, control: bool):
 
     if not control:
         return gap(served), gap(served)
-    low = head(w, hidden(w, c, tokens, "fp8")[0][pos], "fp8")
+    low = head(w, spec.arch(c).hidden(w, c, tokens, "fp8")[0][pos],
+               "fp8")
     return gap(served), gap(jnp.argmax(low, -1))

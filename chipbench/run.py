@@ -8,7 +8,8 @@ Set-up (weights from the seed, every shape the cell's traffic uses warmed,
 programs from JAX's persistent cache in <checkout>/.jax_cache) counts as
 ``setup_s``; then the window measures for --seconds. With --trace 1 the
 profiler records the first TRACE_S seconds of the window, and the line
-carries the per-layer metrics instead of the end-to-end ones. After the
+carries the per-layer metrics instead of the end-to-end ones, read from
+``program_trace.reduce`` of the trace (``run.trace``). After the
 window the program's state is freed and the plain float32 reference
 decides ``correct``.
 
@@ -76,15 +77,21 @@ class Tracer:
         jax.profiler.stop_trace()
 
     def reduce(self):
+        """The trace's reduction (None where there is none), then the trace
+        is deleted."""
         if self.dir is None:
             return None
-        from chipbench import trace_reduce
         try:
             path = glob.glob(f"{self.dir}/**/*.xplane.pb", recursive=True)
-            return trace_reduce.reduce(trace_reduce.load(path[0])) \
-                if path else None
+            return self.reduce_file(path[0]) if path else None
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+
+    def reduce_file(self, path: str):
+        """``program_trace.reduce``: trace_reduce's busy time, top ops and
+        idle gaps, with the program's spans and device programs."""
+        from chipbench import program_trace
+        return program_trace.reduce(program_trace.load(path))
 
 
 def _options():
@@ -112,15 +119,14 @@ class CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self._on)
 
 
-# traffic kind (a traffic file's ``kind``) -> the module that drives it
-DRIVERS = {"train": "chipbench.drive_train", "serve": "chipbench.drive_serve"}
-
-
 def driver(kind: str):
-    if kind not in DRIVERS:
-        raise ValueError(f"unknown traffic kind {kind!r}; "
-                         f"have {sorted(DRIVERS)}")
-    return importlib.import_module(DRIVERS[kind])
+    """The module chipbench/drive_<kind>.py that drives traffic of
+    ``kind`` (a traffic file's ``kind``)."""
+    if not (spec.HERE / f"drive_{kind}.py").is_file():
+        have = sorted(p.stem[len("drive_"):]
+                      for p in spec.HERE.glob("drive_*.py"))
+        raise ValueError(f"unknown traffic kind {kind!r}; have {have}")
+    return importlib.import_module(f"chipbench.drive_{kind}")
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, *, devices,

@@ -3,19 +3,27 @@
 A cell (one entry of ``workloads``) names a configuration and a traffic mix.
 Each has a data file of its own:
 
-    chipbench/configs/<config>.json   sizes as run, with source and cuts
+    chipbench/configs/<config>.json   sizes as run, with source and cuts;
+                                      ``bench_arch`` names its family
+    chipbench/archs/<bench_arch>.py   what the benchmark knows of a
+                                      family's shape: weight layout, the
+                                      float32 reference forward, counts
     chipbench/traffic/<traffic>.json  parameters read by traffic_gen.py and
                                       by the driver its ``kind`` names
+    chipbench/drive_<kind>.py         the driver of a traffic kind
     chipbench/cells/<cell>.json       the limits that decide ``correct``
     chipbench/metrics/<metric>.py     one reader per metric: read(run)
 
-so a later change adds a cell or a metric by adding files and entries.
+so a later change adds a cell, a metric or an architecture by adding
+files and entries.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import re
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Callable, Dict, List
@@ -68,30 +76,45 @@ def load_cell(name: str, bench: Dict[str, Any] | None = None) -> Cell:
                 metrics_of(bench, name, "per_layer"))
 
 
-def reader(metric: str) -> Callable:
-    """``read(run)`` from chipbench/metrics/<metric>.py."""
-    path = HERE / "metrics" / f"{metric}.py"
+def _load(kind: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
+        f"chipbench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-# published config.json keys -> the program's ArchConfig fields
+def reader(metric: str) -> Callable:
+    """``read(run)`` from chipbench/metrics/<metric>.py."""
+    return _load("metric", metric, HERE / "metrics" / f"{metric}.py").read
+
+
+@functools.cache
+def _arch_module(name: str):
+    path = HERE / "archs" / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no architecture module {path.name} in "
+                       f"{path.parent}; have "
+                       f"{sorted(p.stem for p in path.parent.glob('*.py'))}")
+    return _load("arch", name, path)
+
+
+def arch(config: Dict[str, Any]):
+    """The module chipbench/archs/<bench_arch>.py that a configuration
+    names: its weight layout, reference forward and counts."""
+    return _arch_module(config["bench_arch"])
+
+
+# published config.json keys common to every family -> the program's
+# ArchConfig fields; each arch module adds its own in ARCH_FIELDS
 ARCH_FIELDS = {
     "num_hidden_layers": "n_layers",
     "hidden_size": "d_model",
     "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
     "vocab_size": "vocab_size",
     "rms_norm_eps": "norm_eps",
     "rope_theta": "rope_theta",
     "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "qkv_bias",
-    "qk_norm": "qk_norm",
 }
 
 
@@ -105,7 +128,8 @@ def arch_config(config: Dict[str, Any], exact: bool = True):
     """
     from repro.configs import get_config
     base = get_config(config["arch"])
-    fields = {f: config[k] for k, f in ARCH_FIELDS.items() if k in config}
+    keys = {**ARCH_FIELDS, **arch(config).ARCH_FIELDS}
+    fields = {f: config[k] for k, f in keys.items() if k in config}
     fields["param_dtype"] = config["param_dtype"]
     fields.update(config.get("program", {}))
     if not exact:

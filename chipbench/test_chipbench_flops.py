@@ -3,19 +3,21 @@ from __future__ import annotations
 
 import pytest
 
-from chipbench import flops
+from chipbench import flops, spec
 
 # d 8, 2 heads x 4 (q 8), 1 kv head (kv 4), ffn 16, 2 layers, vocab 10
-SMALL = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
+SMALL = {"bench_arch": "qwen_dense", "num_hidden_layers": 2,
+         "hidden_size": 8, "num_attention_heads": 2,
          "num_key_value_heads": 1, "head_dim": 4, "intermediate_size": 16,
          "vocab_size": 10, "tie_word_embeddings": True,
          "attention_bias": True, "qk_norm": True}
+DENSE = spec.arch(SMALL)
 
 
 def test_matmul_params_by_hand():
     # per layer: wq 8x8=64, wk 8x4=32, wv 32, wo 64, gate/up/down 3x128=384
-    assert flops.layer_matmul_params(SMALL) == 576
-    assert flops.matmul_params(SMALL) == 2 * 576 + 10 * 8
+    assert DENSE.layer_matmul_params(SMALL) == 576
+    assert DENSE.matmul_params(SMALL) == 2 * 576 + 10 * 8
 
 
 def test_train_step_by_hand():
@@ -33,11 +35,11 @@ def test_prefill_and_decode_by_hand():
 
 
 def test_bytes_by_hand():
-    assert flops.kv_bytes_per_token(SMALL) == 2 * 2 * 1 * 4 * 2
+    assert DENSE.cache_bytes_per_token(SMALL) == 2 * 2 * 1 * 4 * 2
     # 1152 layer matmul + 80 tied embedding + 2x2x8 norms + final 8
     # + biases 2x(8+4+4) + qk norms 2x2x4
     n = 1152 + 80 + 32 + 8 + 32 + 16
-    assert flops.param_bytes(SMALL) == 2 * n
+    assert DENSE.param_bytes(SMALL) == 2 * n
     assert flops.decode_step_bytes(SMALL, [3, 0]) == 2 * n + 32 * (4 + 1)
 
 

@@ -56,8 +56,8 @@ def test_program_spans_modules_and_gaps(devices):
                                           "wait_us": 1500}
     runs = out["modules"]
     assert runs["serve_prefill"] == pytest.approx([1, 20 * NS])
-    # 50-70 and 95-105 clipped to 95-100
-    assert runs["serve_decode"] == pytest.approx([2, 25 * NS])
+    # 50-70 and 95-105 clipped to 95-100: half of the second run is inside
+    assert runs["serve_decode"] == pytest.approx([1.5, 25 * NS])
     gaps = dict(out["program_gaps"])
     want = {"tick": 2 + 5 + 1 + 5, "serve.admit": 3 + 1 + 2,
             "serve.prefill": 5 + 2, "serve.insert": 5,
@@ -75,7 +75,7 @@ def test_numbers():
     assert n == pytest.approx({
         "chat.queue_wait_ms": 1.5,
         "chat.prefill_ms": 27e-6,
-        "chat.decode_device_ms": 1e3 * 25 * NS / 2,
+        "chat.decode_device_ms": 1e3 * 25 * NS / 1.5,
         "chat.slot_occupancy": (50 + 100) / 2,
         "chat.admit_idle": 100 * (6 + 7 + 5) / 100,
         "chat.sample_idle": 7.0})

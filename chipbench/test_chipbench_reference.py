@@ -31,9 +31,10 @@ def test_logits_match_program(config_name):
     from repro.models.model import forward_hidden, lm_logits
     c, cfg, w, tokens = _setup(config_name)
     with jax.default_matmul_precision("highest"):
-        h, _ = forward_hidden(weights.to_program(w), cfg, tokens)
-        prog = lm_logits(weights.to_program(w), cfg, h).astype(jnp.float32)
-        ref = reference.head(w, reference.hidden(w, c, tokens))
+        p = spec.arch(c).to_program(w)
+        h, _ = forward_hidden(p, cfg, tokens)
+        prog = lm_logits(p, cfg, h).astype(jnp.float32)
+        ref = reference.head(w, spec.arch(c).hidden(w, c, tokens))
     scale = float(jnp.max(jnp.abs(ref)))
     # the program's logits come out in bf16: a relative 2^-8 of the largest
     assert float(jnp.max(jnp.abs(prog - ref))) <= scale / 128
@@ -46,17 +47,20 @@ def test_loss_and_grads_match_program(config_name):
     batch = {"tokens": tokens, "labels": tokens}
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.value_and_grad(
-            lambda p: forward_loss(p, cfg, batch)[0])(weights.to_program(w))
+            lambda p: forward_loss(p, cfg, batch)[0])(
+            spec.arch(c).to_program(w))
         lr, gr = jax.value_and_grad(reference.loss)(w, c, tokens)
     assert abs(float(lp) - float(lr)) < 2e-2    # bf16 logits in the program
-    gp = weights.flat_norms(weights.leaf_norms(weights.from_program(gp)))
-    gr = weights.flat_norms(weights.leaf_norms(gr))
+    gp = weights.flat_norms(weights.leaf_norms(
+        spec.arch(c).from_program(gp), c), c)
+    gr = weights.flat_norms(weights.leaf_norms(gr, c), c)
     assert gp.keys() == gr.keys()
     for k in gr:
         assert gp[k] == pytest.approx(gr[k], rel=5e-2, abs=1e-6), k
 
 
-@pytest.mark.parametrize("module", ["reference", "weights"])
+@pytest.mark.parametrize("module", ["reference", "weights",
+                                    "archs/qwen_dense"])
 def test_reference_is_independent_of_the_program(module):
     import re
     src = (spec.HERE / f"{module}.py").read_text()

@@ -53,7 +53,11 @@ def op_name(text: str) -> str:
 
 def load(path: str) -> Trace:
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(str(path))
+    return from_profile(ProfileData.from_file(str(path)))
+
+
+def from_profile(pd) -> Trace:
+    """The ops and harness spans of a ``jax.profiler.ProfileData``."""
     tr = Trace()
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
