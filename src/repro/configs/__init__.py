@@ -29,15 +29,24 @@ _ALIASES.update({
     "zamba2-2.7b": "zamba2_2_7b",
     "mixtral-8x22b": "mixtral_8x22b",
     "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonshot_v1_16b_a3b",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "whisper-small": "whisper_small",
 })
 
 
+# one chip's share of a deployment, by name -> (module, attribute)
+_SHARES = {"moonlight-16b-a3b.chip": ("moonshot_v1_16b_a3b", "CHIP")}
+
+
 def get_config(name: str) -> ArchConfig:
-    mod_name = _ALIASES.get(name, name).replace("-", "_").replace(".", "_")
+    if name in _SHARES:
+        mod_name, attr = _SHARES[name]
+    else:
+        mod_name, attr = _ALIASES.get(name, name), "CONFIG"
+    mod_name = mod_name.replace("-", "_").replace(".", "_")
     mod = importlib.import_module(f"repro.configs.{mod_name}")
-    return mod.CONFIG
+    return getattr(mod, attr)
 
 
 def all_configs():

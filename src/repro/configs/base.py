@@ -43,11 +43,26 @@ class ArchConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
 
+    # -- multi-head latent attention (DeepSeek-V2/V3; 0 = off) -------------
+    kv_lora_rank: int = 0             # width of the compressed kv latent
+    qk_nope_head_dim: int = 0         # per-head q/k width without RoPE
+    qk_rope_head_dim: int = 0         # per-head q/k width with RoPE (k's
+                                      # part is shared by all heads)
+    v_head_dim: int = 0
+
     # -- MoE ---------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0                # routed experts: the router's width
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0             # experts computed here (0 = all);
+                                      # experts 0 .. experts_held-1
+    n_shared_experts: int = 0         # always-on experts, one SwiGLU of
+                                      # n_shared_experts * d_ff_expert
+    router_score: str = "softmax"     # softmax | sigmoid
+    topk_method: str = "greedy"       # greedy | noaux_tc (a bias that
+                                      # takes part in the choice only)
+    routed_scaling: float = 1.0       # routed output scale
+    first_k_dense: int = 0            # leading layers with a dense MLP
     router_aux_coef: float = 0.01
 
     # -- SSM (Mamba-2) -----------------------------------------------------
@@ -105,7 +120,8 @@ class ArchConfig:
 
     def _derive_pattern(self) -> Tuple[str, ...]:
         if self.family == "moe":
-            return (MOE,) * self.n_layers
+            dense = min(self.first_k_dense, self.n_layers)
+            return (ATTN,) * dense + (MOE,) * (self.n_layers - dense)
         if self.family == "ssm":          # xLSTM
             pat = []
             for i in range(self.n_layers):
@@ -126,6 +142,32 @@ class ArchConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights live here."""
+        return self.experts_held or self.n_experts
+
+    def attn_param_count(self) -> int:
+        d, H = self.d_model, self.n_heads
+        if self.mla:
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (d * H * qk                                   # wq
+                    + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                    + self.kv_lora_rank                          # kv_a_norm
+                    + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                    + H * self.v_head_dim * d)                   # wo
+        n = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        if self.qkv_bias:
+            n += self.q_dim + 2 * self.kv_dim
+        if self.qk_norm:
+            n += 2 * self.head_dim
+        return n
+
+    @property
     def is_subquadratic(self) -> bool:
         """True if the arch can serve 500k-token contexts (skip rule)."""
         kinds = set(self.block_pattern)
@@ -135,26 +177,25 @@ class ArchConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (used for roofline MODEL_FLOPS)."""
-        d, hd = self.d_model, self.head_dim
+        d = self.d_model
         n = 0
         emb = self.vocab_size * d
         n += emb if self.tie_embeddings else 2 * emb
         for kind in self.block_pattern:
             n += d  # ln1
             if kind == ATTN or kind == MOE:
-                n += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-                if self.qkv_bias:
-                    n += self.q_dim + 2 * self.kv_dim
-                if self.qk_norm:
-                    n += 2 * hd
+                n += self.attn_param_count()
                 n += d  # ln2
+                mult = 3 if self.gated_mlp else 2
                 if kind == ATTN and self.d_ff:
-                    mult = 3 if self.gated_mlp else 2
                     n += mult * d * self.d_ff
                 elif kind == MOE:
-                    mult = 3 if self.gated_mlp else 2
-                    n += self.n_experts * mult * d * self.d_ff_expert
+                    n += self.n_held * mult * d * self.d_ff_expert
+                    n += (self.n_shared_experts * mult * d
+                          * self.d_ff_expert)
                     n += d * self.n_experts  # router
+                    if self.topk_method == "noaux_tc":
+                        n += self.n_experts  # selection bias
             elif kind == MAMBA2:
                 d_in = self.ssm_expand * d
                 nheads = d_in // self.ssm_head_dim
@@ -200,9 +241,21 @@ class ArchConfig:
             return self.param_count()
         d = self.d_model
         mult = 3 if self.gated_mlp else 2
-        dead = (self.n_experts - self.top_k) * mult * d * self.d_ff_expert
+        dead = (self.n_held - self.top_k * self.n_held / self.n_experts
+                ) * mult * d * self.d_ff_expert
         return int(self.param_count() - len([k for k in self.block_pattern
                                              if k == MOE]) * dead)
+
+    def chip_share(self, name: str, layers: int, experts_held: int,
+                   vocab_size: int) -> "ArchConfig":
+        """One chip's share of a deployment at published widths: the first
+        ``layers`` layers (the rest would lie on further pipeline stages),
+        experts 0 .. ``experts_held``-1 of each expert layer (the router
+        keeps all ``n_experts`` outputs) and the first ``vocab_size`` rows of
+        the vocabulary."""
+        return dataclasses.replace(
+            self, name=name, n_layers=layers, experts_held=experts_held,
+            vocab_size=vocab_size, block_pattern=())
 
     def reduced(self) -> "ArchConfig":
         """Tiny same-family config for CPU smoke tests."""
@@ -220,8 +273,13 @@ class ArchConfig:
             d_ff_expert=128 if self.d_ff_expert else 0,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            experts_held=min(self.experts_held, 2),
+            first_k_dense=min(self.first_k_dense, 1),
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_head_dim=16 if self.qk_nope_head_dim else 0,
+            qk_rope_head_dim=8 if self.qk_rope_head_dim else 0,
+            v_head_dim=16 if self.v_head_dim else 0,
             vocab_size=256,
-            capacity_factor=4.0,
             ssm_state=16 if self.ssm_state else 0,
             ssm_head_dim=32 if self.ssm_state or self.family == "ssm" else 64,
             sliding_window=64 if self.sliding_window else 0,

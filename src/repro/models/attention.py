@@ -1,4 +1,5 @@
-"""Attention: GQA with qk-norm / biases / RoPE / M-RoPE / sliding window.
+"""Attention: GQA with qk-norm / biases / RoPE / M-RoPE / sliding window,
+and multi-head latent attention (MLA, DeepSeek-V2/V3).
 
 Three implementations share one math definition (``ref`` oracle):
   * ``naive``   — materializes [T, S] scores (smoke tests, tiny shapes)
@@ -9,7 +10,12 @@ Three implementations share one math definition (``ref`` oracle):
                   Selected via cfg.attn_impl == "pallas"; any other backend
                   raises rather than running the Pallas interpreter.
 
-Decode attends one new token against a (possibly rolling) KV cache.
+Decode attends one new token against a (possibly rolling) KV cache. MLA
+runs in training and full forwards only: it has no latent cache yet, so
+prefill and decode refuse it (``no_latent_cache``).
+
+v may be narrower than q and k (MLA: q.k over 192 dims, v over 128); scores
+are scaled by q's width and the output has v's.
 """
 from __future__ import annotations
 
@@ -24,12 +30,17 @@ from .common import (F32, apply_mrope, apply_rope, dense_init, matmul,
                      rms_norm, zeros)
 
 NEG_INF = -1e30
+# attend_chunked checkpoints each query block when the probabilities of all
+# blocks, which its backward would otherwise keep, exceed this many bytes
+SAVED_PROBS_BYTES = 1 << 30
 
 
 # --------------------------------------------------------------------------
 # params
 # --------------------------------------------------------------------------
 def init_attn_params(key, cfg, dtype, cross: bool = False):
+    if cfg.mla and not cross:
+        return init_mla_params(key, cfg, dtype)
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     ks = jax.random.split(key, 4)
     p = {
@@ -46,6 +57,32 @@ def init_attn_params(key, cfg, dtype, cross: bool = False):
         p["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
         p["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
     return p
+
+
+def init_mla_params(key, cfg, dtype):
+    """q straight from x (no q LoRA); k and v from a normed latent of
+    ``kv_lora_rank`` made beside the shared RoPE part of k (``wkv_a``)."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, H * (nope + rope), dtype),
+        "wkv_a": dense_init(ks[1], d, r + rope, dtype),
+        "kv_a_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(ks[2], r, H * (nope + vd), dtype),
+        "wo": dense_init(ks[3], H * vd, d, dtype,
+                         scale=1.0 / math.sqrt(2 * max(cfg.n_layers, 1))),
+    }
+
+
+def no_latent_cache(cfg):
+    """Prefill and decode of an MLA config: refused, not approximated."""
+    raise NotImplementedError(
+        f"{cfg.name}: multi-head latent attention has no latent cache "
+        f"(kv_lora_rank {cfg.kv_lora_rank} + rope {cfg.qk_rope_head_dim} a "
+        f"token) to prefill or decode through; it trains and runs full "
+        f"forwards only")
 
 
 # --------------------------------------------------------------------------
@@ -103,7 +140,8 @@ def mask_bias(q_pos, k_pos, causal: bool, window: int):
 
 
 def attend_naive(q, k, v, *, causal: bool, window: int, q_offset: int = 0):
-    """q: [B,T,H,hd], k/v: [B,S,KV,hd] -> [B,T,H,hd]. Materializes scores."""
+    """q/k: [B,T,H,hd], [B,S,KV,hd]; v: [B,S,KV,vd] -> [B,T,H,vd].
+    Materializes scores."""
     B, T, H, hd = q.shape
     S = k.shape[1]
     k = _expand_kv(k, H)
@@ -120,12 +158,27 @@ def attend_naive(q, k, v, *, causal: bool, window: int, q_offset: int = 0):
     return out.astype(q.dtype)
 
 
+def _softmax_apart(scores):
+    """Softmax over the last axis with the row max in a fusion of its own.
+    Fused with the subtraction, XLA's TPU compiler may take the max of a
+    long row as a reduce-window over the whole row for every element
+    (O(S^2) a row): 47 ms for one [2, 16, 512, 8192] block of latent
+    attention on a v5e, against ~1 ms to read it."""
+    m = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(scores, -1, keepdims=True)))
+    e = jnp.exp(scores - m)
+    return e / jnp.sum(e, -1, keepdims=True)
+
+
 def attend_chunked(q, k, v, *, causal: bool, window: int, q_offset: int = 0,
                    q_block: int = 512):
     """Flash-style: map over query blocks, online-softmax over KV.
 
     Memory O(q_block * S) instead of O(T * S). Pure jnp; the Pallas kernel in
     repro.kernels.flash_attention is the TPU-tiled version of this schedule.
+    Where the backward would keep more than SAVED_PROBS_BYTES of
+    probabilities, each block is recomputed in the backward instead. The
+    softmax takes the row max apart (``_softmax_apart``).
     """
     B, T, H, hd = q.shape
     S = k.shape[1]
@@ -150,12 +203,14 @@ def attend_chunked(q, k, v, *, causal: bool, window: int, q_offset: int = 0,
         if window > 0:
             ok = ok & (k_pos[None, :] > q_pos[:, None] - window)
         scores = jnp.where(ok[None, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
+        probs = _softmax_apart(scores)
         return jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v,
                           preferred_element_type=F32).astype(q.dtype)
 
+    if B * H * T * S * 4 > SAVED_PROBS_BYTES:
+        one_block = jax.checkpoint(one_block)
     out = jax.lax.map(one_block, (qb, jnp.arange(n_blocks)))
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, T, H, hd)
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, T, H, v.shape[-1])
 
 
 def attend_scan_kv(q, k, v, *, causal: bool, window: int, q_offset: int = 0,
@@ -177,7 +232,7 @@ def attend_scan_kv(q, k, v, *, causal: bool, window: int, q_offset: int = 0,
     scale = 1.0 / math.sqrt(hd)
     nb = S // kv_block
     kb = k.reshape(B, nb, kv_block, H, hd).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nb, kv_block, H, hd).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, nb, kv_block, H, -1).transpose(1, 0, 2, 3, 4)
     q32 = q.astype(F32)
     q_pos = (jnp.arange(T) + q_offset)[:, None]
 
@@ -200,7 +255,7 @@ def attend_scan_kv(q, k, v, *, causal: bool, window: int, q_offset: int = 0,
             "bhts,bshd->bhtd", p, vj.astype(F32))
         return (acc_new, m_new, l_new), None
 
-    acc0 = jnp.zeros((B, H, T, hd), F32)
+    acc0 = jnp.zeros((B, H, T, v.shape[-1]), F32)
     m0 = jnp.full((B, H, T), NEG_INF, F32)
     l0 = jnp.zeros((B, H, T), F32)
     (acc, m, l), _ = jax.lax.scan(step, (acc0, m0, l0),
@@ -262,6 +317,9 @@ def attend(q, k, v, cfg, *, causal: bool = True, q_offset: int = 0,
                                  q_offset=q_offset)
         return pctx.constrain(out, dp, "model", None, None)
     if impl == "pallas":
+        if v.shape[-1] != q.shape[-1]:
+            raise ValueError("attn_impl='pallas' needs v as wide as q; "
+                             "use 'chunked'")
         if jax.default_backend() != "tpu":
             raise RuntimeError(
                 f"attn_impl='pallas' compiles for the TPU; the backend is "
@@ -282,6 +340,8 @@ def attend(q, k, v, cfg, *, causal: bool = True, q_offset: int = 0,
 def attn_forward(p, cfg, x, *, pos, pos3=None, causal=True, kv_x=None,
                  use_rope=True):
     """Full-sequence attention (training / encoder). Returns [B, T, d]."""
+    if "wkv_a" in p:
+        return mla_forward(p, cfg, x, pos=pos)
     q, k, v = _project_qkv(p, cfg, x, kv_x)
     if use_rope:
         q, k = _rope_qk(q, k, cfg, pos, pos3)
@@ -290,8 +350,34 @@ def attn_forward(p, cfg, x, *, pos, pos3=None, causal=True, kv_x=None,
     return matmul(out.reshape(B, T, cfg.q_dim), p["wo"])
 
 
+def mla_forward(p, cfg, x, *, pos):
+    """Multi-head latent attention over the full sequence -> [B, T, d].
+
+    RoPE rotates only the ``qk_rope_head_dim`` part of q and k; k's part
+    comes from x once and is shared by every head. q.k runs over nope +
+    rope dims (scaled by 1/sqrt of that), v over ``v_head_dim``.
+    """
+    B, T, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    q = matmul(x, p["wq"]).reshape(B, T, H, -1)
+    kv_a = matmul(x, p["wkv_a"])
+    latent = rms_norm(kv_a[..., :r], p["kv_a_norm"], cfg.norm_eps)
+    kv = matmul(latent, p["wkv_b"]).reshape(B, T, H, nope + vd)
+    k_rope = apply_rope(kv_a[:, :, None, r:], pos, cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], pos, cfg.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope, (B, T, H, k_rope.shape[-1]))], -1)
+    out = attend(q, k, kv[..., nope:], cfg, causal=True)
+    return matmul(out.reshape(B, T, H * vd), p["wo"])
+
+
 def attn_prefill(p, cfg, x, *, pos, pos3=None):
     """Training-style pass that also returns the KV cache (k, v)."""
+    if cfg.mla:
+        no_latent_cache(cfg)
     q, k, v = _project_qkv(p, cfg, x)
     q, k = _rope_qk(q, k, cfg, pos, pos3)
     out = attend(q, k, v, cfg, causal=True)
@@ -307,6 +393,8 @@ def attn_decode(p, cfg, x, cache, *, cache_len, pos3=None, rolling=False):
     written at ``cache_len % S`` when ``rolling`` (sliding window) else at
     ``cache_len``. Returns (out [B,1,d], new_cache).
     """
+    if cfg.mla:
+        no_latent_cache(cfg)
     from repro.parallel import ctx as pctx
     plan = pctx.plan_or_none()
     k_cache, v_cache = cache
@@ -433,5 +521,7 @@ def cross_kv(p, cfg, enc_out):
 
 
 def init_kv_cache(cfg, batch: int, max_seq: int, dtype):
+    if cfg.mla:
+        no_latent_cache(cfg)
     shape = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))

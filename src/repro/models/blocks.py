@@ -5,8 +5,11 @@ plus the enc-dec decoder block (self-attn + cross-attn + MLP) used by
 whisper, and the zamba2 shared attention block (ATTN kind, weights shared
 across applications).
 
-All forwards return (x, aux) where aux is a scalar auxiliary loss (MoE load
-balance; 0.0 elsewhere) so stages can be scanned uniformly.
+Full-sequence forwards return (x, aux, rows): aux is a scalar auxiliary
+loss (MoE load balance; 0.0 elsewhere), so stages can be scanned uniformly,
+and rows counts the routed rows each held expert computed (MoE blocks; None
+elsewhere). MLA blocks have no cache: prefill, decode and cache init refuse
+them.
 """
 from __future__ import annotations
 
@@ -15,7 +18,7 @@ import jax.numpy as jnp
 
 from .attention import (attn_decode, attn_decode_cross, attn_forward,
                         attn_prefill, cross_kv, init_attn_params,
-                        init_kv_cache)
+                        init_kv_cache, no_latent_cache)
 from .common import F32, rms_norm
 from .mlp import init_mlp_params, init_moe_params, mlp_forward, moe_forward
 from .ssm import (init_mamba2_cache, init_mamba2_params, mamba2_decode,
@@ -90,18 +93,22 @@ def block_forward(kind: str, p, cfg, x, *, pos, pos3=None, enc_out=None,
                                  use_rope=False)
         hn = rms_norm(h, p["ln2"], cfg.norm_eps)
         if kind == "moe":
-            y, aux = moe_forward(p["moe"], cfg, hn)
-            return h + _seq_constrain(cfg, y), aux
-        return h + _seq_constrain(cfg, mlp_forward(p["mlp"], cfg, hn)), ZERO
+            y, aux, rows = moe_forward(p["moe"], cfg, hn)
+            return h + _seq_constrain(cfg, y), aux, rows
+        return (h + _seq_constrain(cfg, mlp_forward(p["mlp"], cfg, hn)),
+                ZERO, None)
     if kind == "mamba2":
         return x + mamba2_forward(p["mixer"], cfg,
-                                  rms_norm(x, p["ln1"], cfg.norm_eps)), ZERO
+                                  rms_norm(x, p["ln1"], cfg.norm_eps)), \
+            ZERO, None
     if kind == "mlstm":
         return x + mlstm_forward(p["mixer"], cfg,
-                                 rms_norm(x, p["ln1"], cfg.norm_eps)), ZERO
+                                 rms_norm(x, p["ln1"], cfg.norm_eps)), \
+            ZERO, None
     if kind == "slstm":
         return x + slstm_forward(p["mixer"], cfg,
-                                 rms_norm(x, p["ln1"], cfg.norm_eps)), ZERO
+                                 rms_norm(x, p["ln1"], cfg.norm_eps)), \
+            ZERO, None
     raise ValueError(kind)
 
 
@@ -116,6 +123,8 @@ def block_prefill(kind: str, p, cfg, x, *, pos, pos3=None, enc_out=None,
     decode's ``cache_len % W`` write lands on the oldest entry.
     """
     if kind in ("attn", "moe"):
+        if cfg.mla:
+            no_latent_cache(cfg)
         hn = rms_norm(x, p["ln1"], cfg.norm_eps)
         a_out, (k, v) = attn_prefill(p["attn"], cfg, hn, pos=pos, pos3=pos3)
         h = x + a_out
@@ -147,7 +156,7 @@ def block_prefill(kind: str, p, cfg, x, *, pos, pos3=None, enc_out=None,
             cache["xkv"] = cross_kv(p["xattn"], cfg, enc_out)
         hn2 = rms_norm(h, p["ln2"], cfg.norm_eps)
         if kind == "moe":
-            y, _ = moe_forward(p["moe"], cfg, hn2)
+            y, _, _ = moe_forward(p["moe"], cfg, hn2)
             return h + y, cache
         return h + mlp_forward(p["mlp"], cfg, hn2), cache
     # recurrent kinds: run chunked forward capturing final state
@@ -248,6 +257,8 @@ def _recurrent_prefill_slstm(p, cfg, x):
 # --------------------------------------------------------------------------
 def block_decode(kind: str, p, cfg, x, cache, *, cache_len, rolling=False):
     if kind in ("attn", "moe"):
+        if cfg.mla:
+            no_latent_cache(cfg)
         hn = rms_norm(x, p["ln1"], cfg.norm_eps)
         a_out, kv = attn_decode(p["attn"], cfg, hn, cache["kv"],
                                 cache_len=cache_len, rolling=rolling)
@@ -260,7 +271,7 @@ def block_decode(kind: str, p, cfg, x, cache, *, cache_len, rolling=False):
                 cache["xkv"])
         hn2 = rms_norm(h, p["ln2"], cfg.norm_eps)
         if kind == "moe":
-            y, _ = moe_forward(p["moe"], cfg, hn2, inference=True)
+            y, _, _ = moe_forward(p["moe"], cfg, hn2)
             return h + y, new_cache
         return h + mlp_forward(p["mlp"], cfg, hn2), new_cache
     if kind == "mamba2":

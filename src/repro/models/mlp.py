@@ -1,10 +1,17 @@
-"""Dense MLP (gated SwiGLU / ungated squared-ReLU / GELU) and sort-based MoE.
+"""Dense MLP (gated SwiGLU / ungated squared-ReLU / GELU) and a drop-free
+MoE layer.
 
-MoE uses the capacity-bucketed sort dispatch: tokens are argsorted by expert
-assignment, scattered into an [E, C, d] buffer (drops beyond capacity),
-pushed through a batched expert matmul, and combined back weighted by router
-probabilities. Expert-parallel sharding: the E dim shards over 'model' when
-divisible, otherwise d_ff_expert shards over 'model' (TP inside experts).
+The MoE layer routes each token over all ``n_experts`` (softmax or sigmoid
+scores; a selection bias may take part in the choice only), and computes
+the experts held here (``experts_held``, a chip's share under expert
+parallelism; all by default) on exactly the rows routed to them: the
+(token, choice) rows are sorted by expert and each held expert's group runs
+through a grouped matrix product, ``jax.lax.ragged_dot``, which the TPU
+runs as a Mosaic kernel of its own name. No token is ever dropped, and
+training, prefill and decode take the same path. Shared
+experts, where the config has them, are one dense SwiGLU beside the routed
+part. What experts held elsewhere add is left out: under expert
+parallelism it arrives from the other chips.
 """
 from __future__ import annotations
 
@@ -41,77 +48,95 @@ def mlp_forward(p, cfg, x):
 # MoE
 # --------------------------------------------------------------------------
 def init_moe_params(key, cfg, dtype):
-    E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
-    ks = jax.random.split(key, 4)
+    E, d, f = cfg.n_held, cfg.d_model, cfg.d_ff_expert
+    ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], d, E, jnp.float32),
+        "router": dense_init(ks[0], d, cfg.n_experts, jnp.float32),
         "w_up": (jax.random.normal(ks[1], (E, d, f), F32) / d ** 0.5).astype(dtype),
         "w_down": (jax.random.normal(ks[2], (E, f, d), F32) / f ** 0.5).astype(dtype),
     }
     if cfg.gated_mlp:
         p["w_gate"] = (jax.random.normal(ks[3], (E, d, f), F32) / d ** 0.5).astype(dtype)
+    if cfg.topk_method == "noaux_tc":
+        p["e_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp_params(ks[4], cfg, dtype,
+                                      cfg.n_shared_experts * cfg.d_ff_expert)
     return p
 
 
-def moe_forward(p, cfg, x, inference: bool = False):
-    """x: [B, T, d] -> (y, aux_loss).
+def route(p, cfg, xf):
+    """Router over all experts: (chosen experts [N, K], their weights
+    [N, K] f32, normalised to sum 1 and scaled, load-balance aux loss)."""
+    E, K = cfg.n_experts, cfg.top_k
+    logits = matmul(xf, p["router"].astype(xf.dtype), out_dtype=F32)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if "e_bias" in p:
+        # the bias chooses, the unbiased scores weigh
+        choice = scores + jax.lax.stop_gradient(p["e_bias"].astype(F32))
+    _, top_e = jax.lax.top_k(choice, K)                               # [N, K]
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    top_w = top_w * cfg.routed_scaling
+    aux = jnp.zeros((), F32)
+    if cfg.router_aux_coef:
+        # Switch-style load balance over the normalised scores
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        assign = jnp.zeros((E,), F32).at[top_e.reshape(-1)].add(1.0)
+        aux = (E * jnp.sum(jnp.mean(probs, axis=0) * assign)
+               / top_e.size * cfg.router_aux_coef)
+    return top_e, top_w, aux
 
-    ``inference`` selects drop-free capacity (C = N): correct single-token
-    decode requires that no routed token is ever dropped.
+
+def grouped_matmul(rows, w, group_sizes):
+    """rows [M, k] sorted by group, w [G, k, n] -> [M, n]; rows past the
+    groups' total are not defined (the caller masks them). On the TPU,
+    ragged_dot is a Mosaic kernel (``ragged-dot-none.N`` in a trace)."""
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=F32).astype(rows.dtype)
+
+
+def moe_forward(p, cfg, x, first_expert: int = 0):
+    """x: [B, T, d] -> (y, aux_loss, rows [experts held] int32).
+
+    The held experts are ``first_expert`` .. ``first_expert +
+    cfg.n_held - 1``; ``rows`` counts the (token, choice) rows each of them
+    computed.
     """
     B, T, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    N = B * T
+    N, K, Eh = B * T, cfg.top_k, cfg.n_held
     xf = x.reshape(N, d)
+    top_e, top_w, aux = route(p, cfg, xf)
 
-    logits = matmul(xf, p["router"].astype(xf.dtype), out_dtype=F32)  # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                            # [N, K]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)            # renorm
+    # (token, choice) rows of held experts first, sorted by expert; the
+    # others after them, where no product reaches
+    local = top_e.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < Eh), local, Eh)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+    valid = (jnp.arange(N * K) < jnp.sum(group_sizes))[:, None]
+    token = order // K
+    rows = jnp.where(valid, xf[token], 0)
 
-    # ---- load-balancing aux loss (Switch-style) ----
-    me = jnp.mean(probs, axis=0)                                      # [E]
-    assign = jnp.zeros((E,), F32).at[top_e.reshape(-1)].add(1.0) / (N * K)
-    aux = E * jnp.sum(me * assign) * cfg.router_aux_coef
-
-    # ---- sort-based dispatch ----
-    if inference:
-        C = N          # drop-free: an expert can receive at most N tokens
-    else:
-        C = int(max(1, round(N * K / E * cfg.capacity_factor)))
-    C = min(C, N)
-    flat_e = top_e.reshape(-1)                                        # [N*K]
-    sort_idx = jnp.argsort(flat_e)                                    # stable
-    sorted_e = flat_e[sort_idx]
-    token_of = sort_idx // K                                          # source token
-    # position of each routed slot within its expert group
-    starts = jnp.searchsorted(sorted_e, jnp.arange(E), side="left")
-    pos_in_e = jnp.arange(N * K) - starts[sorted_e]
-    keep = pos_in_e < C
-
-    buf = jnp.zeros((E, C, d), x.dtype)
-    src = xf[token_of]                                                # [N*K, d]
-    e_idx = jnp.where(keep, sorted_e, 0)
-    c_idx = jnp.where(keep, pos_in_e, 0)
-    src = jnp.where(keep[:, None], src, 0)
-    buf = buf.at[e_idx, c_idx].add(src)                               # [E, C, d]
-
-    # ---- expert compute (batched over E) ----
     act = activation_fn(cfg.activation)
-    up = jnp.einsum("ecd,edf->ecf", buf, p["w_up"],
-                    preferred_element_type=F32).astype(x.dtype)
     if "w_gate" in p:
-        gate = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"],
-                          preferred_element_type=F32)
-        h = act(gate).astype(x.dtype) * up
+        # gate and up in one product: one kernel, twice as wide
+        f = p["w_up"].shape[-1]
+        gate_up = grouped_matmul(
+            rows, jnp.concatenate([p["w_gate"], p["w_up"]], -1), group_sizes)
+        h = act(gate_up[:, :f].astype(F32)).astype(x.dtype) * gate_up[:, f:]
     else:
+        up = grouped_matmul(rows, p["w_up"], group_sizes)
         h = act(up.astype(F32)).astype(x.dtype)
-    y_buf = jnp.einsum("ecf,efd->ecd", h, p["w_down"],
-                       preferred_element_type=F32).astype(x.dtype)    # [E, C, d]
-
-    # ---- combine ----
-    gathered = y_buf[e_idx, c_idx]                                    # [N*K, d]
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    w = top_p.reshape(-1)[sort_idx].astype(x.dtype)                   # [N*K]
-    out = jnp.zeros((N, d), x.dtype).at[token_of].add(gathered * w[:, None])
-    return out.reshape(B, T, d), aux
+    y = grouped_matmul(h, p["w_down"], group_sizes)
+    # masked before the weighting: the weight's gradient then never meets
+    # an undefined row
+    y = jnp.where(valid, y.astype(F32), 0) * top_w.reshape(-1)[order][:, None]
+    out = jnp.zeros((N, d), F32).at[token].add(y)
+    if "shared" in p:
+        out = out + mlp_forward(p["shared"], cfg, xf).astype(F32)
+    return out.astype(x.dtype).reshape(B, T, d), aux, group_sizes

@@ -115,7 +115,8 @@ def _remat_wrap(fn, cfg):
 
 def run_stage(kind, stage_params, cfg, x, *, pos, pos3=None, enc_out=None,
               causal=True):
-    """Scan the stacked blocks of one stage. Returns (x, aux_sum)."""
+    """Scan the stacked blocks of one stage. Returns (x, aux_sum, rows):
+    rows [blocks, experts held] for an MoE stage, else None."""
     from repro.parallel import ctx as pctx
 
     def body(x, layer_p):
@@ -132,11 +133,11 @@ def run_stage(kind, stage_params, cfg, x, *, pos, pos3=None, enc_out=None,
 
     def scan_fn(carry, layer_p):
         x, aux = carry
-        x2, a = body(x, layer_p)
-        return (x2, aux + a), None
+        x2, a, rows = body(x, layer_p)
+        return (x2, aux + a), rows
 
-    (x, aux), _ = jax.lax.scan(scan_fn, (x, ZERO), stage_params)
-    return x, aux
+    (x, aux), rows = jax.lax.scan(scan_fn, (x, ZERO), stage_params)
+    return x, aux, rows
 
 
 def run_stage_prefill(kind, stage_params, cfg, x, *, pos, pos3=None,
@@ -196,8 +197,8 @@ def encode(p, cfg, frames):
                            frames.shape[:2])
 
     def body(x, layer_p):
-        x2, _ = block_forward("attn", layer_p, cfg, x, pos=pos, causal=False)
-        return x2
+        return block_forward("attn", layer_p, cfg, x, pos=pos,
+                             causal=False)[0]
     body = _remat_wrap(body, cfg)
 
     def scan_fn(x, layer_p):
@@ -209,35 +210,48 @@ def encode(p, cfg, frames):
 # --------------------------------------------------------------------------
 # full forward (training)
 # --------------------------------------------------------------------------
-def forward_hidden(p, cfg, tokens, *, pos=None, pos3=None, enc_out=None,
-                   patch_embeds=None, patch_pos=None):
+def forward_hidden(p, cfg, tokens, **kw):
+    """Final hidden states [B, T, d] (before the final norm) and the
+    auxiliary loss."""
+    h, aux, _ = forward_with_rows(p, cfg, tokens, **kw)
+    return h, aux
+
+
+def forward_with_rows(p, cfg, tokens, *, pos=None, pos3=None, enc_out=None,
+                      patch_embeds=None, patch_pos=None):
+    """forward_hidden's (h, aux) and the routed rows of each held expert
+    of each MoE block, [MoE blocks, experts held] int32 (None without
+    experts)."""
     B, T = tokens.shape
     if pos is None:
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     h = embed_tokens(p, cfg, tokens, patch_embeds, patch_pos)
-    aux = ZERO
+    aux, rows = ZERO, []
     stages = pattern_stages(cfg)
     for si, (kind, _) in enumerate(stages):
-        h, a = run_stage(kind, p["stages"][si], cfg, h, pos=pos, pos3=pos3,
-                         enc_out=enc_out)
+        h, a, r = run_stage(kind, p["stages"][si], cfg, h, pos=pos,
+                            pos3=pos3, enc_out=enc_out)
         aux = aux + a
+        if r is not None:
+            rows.append(r)
         if cfg.shared_attn_every:
-            h, a2 = block_forward("attn", p["shared"], cfg, h, pos=pos)
+            h, a2, _ = block_forward("attn", p["shared"], cfg, h, pos=pos)
             aux = aux + a2
-    return h, aux
+    return h, aux, jnp.concatenate(rows) if rows else None
 
 
 def forward_loss(p, cfg, batch) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """batch: {tokens [B,T], labels [B,T] (-1 = ignore), + modality extras}.
 
-    Returns (loss, metrics).
+    Returns (loss, metrics); with experts, metrics["expert_rows"] holds
+    the rows each held expert of each MoE block computed.
     """
     tokens = batch["tokens"]
     labels = batch["labels"]
     enc_out = None
     if cfg.enc_dec:
         enc_out = encode(p, cfg, batch["frames"])
-    h, aux = forward_hidden(
+    h, aux, rows = forward_with_rows(
         p, cfg, tokens,
         pos3=batch.get("pos3"),
         enc_out=enc_out,
@@ -257,6 +271,8 @@ def forward_loss(p, cfg, batch) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     loss = jnp.sum(nll) / denom
     metrics = {"nll": loss, "aux": aux,
                "ntokens": jnp.sum(mask)}
+    if rows is not None:
+        metrics["expert_rows"] = rows
     return loss + aux, metrics
 
 

@@ -16,7 +16,7 @@ Policy matrix (decided per arch from static divisibility, see DESIGN.md §5):
   decode     batch over data; KV cache: kv-heads over 'model' when divisible,
              else cache sequence dim over 'model' (flash-decoding style
              distributed softmax, GSPMD inserts the reductions).
-  MoE        expert dim over 'model' when n_experts divisible (EP);
+  MoE        expert dim over 'model' when the experts held divide it (EP);
              otherwise d_ff_expert over 'model' (TP inside each expert).
 """
 from __future__ import annotations
@@ -73,7 +73,7 @@ def make_plan(cfg: ArchConfig, mesh: Mesh) -> ShardingPlan:
         dp_total=dp_total,
         tp_heads=tp_heads,
         tp_kv_heads=_div(cfg.n_kv_heads, m),
-        ep=_div(cfg.n_experts, m),
+        ep=_div(cfg.n_held, m),
         vocab_tp=_div(cfg.vocab_size, m),
         fsdp=cfg.fsdp,
         context_parallel=not tp_heads,
@@ -85,8 +85,8 @@ def make_plan(cfg: ArchConfig, mesh: Mesh) -> ShardingPlan:
 # param specs
 # --------------------------------------------------------------------------
 _VECTOR_NAMES = ("ln1", "ln2", "ln_x", "final_norm", "enc_norm", "norm",
-                 "gn", "ff_ln", "q_norm", "k_norm", "A_log", "D", "dt_bias",
-                 "b", "b_i", "b_f")
+                 "gn", "ff_ln", "q_norm", "k_norm", "kv_a_norm", "e_bias",
+                 "A_log", "D", "dt_bias", "b", "b_i", "b_f")
 
 
 def param_specs(cfg: ArchConfig, mesh: Mesh,
@@ -136,6 +136,8 @@ def param_specs(cfg: ArchConfig, mesh: Mesh,
                 return spec(fsdp(shape[0]), m if plan.tp_kv_heads else None)
             if name == "wo":
                 return spec(m if plan.tp_heads else None, fsdp(shape[1]))
+            if name == "wkv_b":               # MLA: latent -> heads
+                return spec(None, m if plan.tp_heads else None)
             if name == "bq":
                 return spec(m if plan.tp_heads else None)
             if name in ("bk", "bv"):

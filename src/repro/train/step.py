@@ -3,6 +3,8 @@
 The step is one jitted SPMD program:
   scan over microbatches { remat'd forward, backward, fp32 grad accumulate }
   -> AdamW update (moments sharded like params).
+With experts, its metrics also hold ``expert_rows``: the rows each held
+expert of each MoE block computed, summed over the microbatches.
 
 Accumulation exposes per-microbatch collectives to XLA's latency-hiding
 scheduler (compute/comm overlap). ``grad_compress="int8"`` swaps the final
@@ -63,6 +65,14 @@ def _microbatch_stack(batch, k: int):
     return {name: rs(name, x) for name, x in batch.items()}
 
 
+def expert_rows0(cfg: ArchConfig):
+    """Zero count of the rows each held expert of each MoE block computed
+    ([MoE blocks, experts held] int32), or None for a model without
+    experts."""
+    n = sum(1 for kind in cfg.block_pattern if kind == "moe")
+    return jnp.zeros((n, cfg.n_held), jnp.int32) if n else None
+
+
 def make_train_step(cfg: ArchConfig, mesh: Mesh, *,
                     peak_lr: float = 3e-4, warmup: int = 100,
                     total_steps: int = 10_000,
@@ -82,7 +92,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, *,
     def train_step(params, opt_state, batch, step):
         with sharding_ctx(mesh, plan):
             def micro(carry, mb):
-                g_acc, loss_acc = carry
+                g_acc, loss_acc, rows_acc = carry
                 # re-pin the microbatch sharding: scan's leading-axis slice
                 # must not change the batch-dim placement
                 mb = jax.tree_util.tree_map(
@@ -100,12 +110,15 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, *,
                         g, NamedSharding(mesh, s)), grads, psp)
                 g_acc = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(F32), g_acc, grads)
-                return (g_acc, loss_acc + loss), None
+                if rows_acc is not None:
+                    rows_acc = rows_acc + metrics["expert_rows"]
+                return (g_acc, loss_acc + loss, rows_acc), None
 
             g0 = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, F32), params)
-            (grads, loss_sum), _ = jax.lax.scan(
-                micro, (g0, jnp.zeros((), F32)), _microbatch_stack(batch, k))
+            (grads, loss_sum, rows), _ = jax.lax.scan(
+                micro, (g0, jnp.zeros((), F32), expert_rows0(cfg)),
+                _microbatch_stack(batch, k))
             grads = jax.tree_util.tree_map(lambda g: g / k, grads)
             loss = loss_sum / k
 
@@ -122,12 +135,16 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, *,
             new_params, new_opt, gnorm = adamw_update(
                 grads, opt_state, params, lr=lr)
             metrics = {"loss": loss, "lr": lr, "grad_norm": gnorm}
+            if rows is not None:
+                metrics["expert_rows"] = rows
             return new_params, new_opt, metrics
 
     opt_spec = {"m": psp, "v": psp, "count": P()}
     in_shardings = (psp, opt_spec, bsp, P())
-    out_shardings = (psp, opt_spec,
-                     {"loss": P(), "lr": P(), "grad_norm": P()})
+    metric_spec = {"loss": P(), "lr": P(), "grad_norm": P()}
+    if expert_rows0(cfg) is not None:
+        metric_spec["expert_rows"] = P()
+    out_shardings = (psp, opt_spec, metric_spec)
     return train_step, in_shardings, out_shardings
 
 

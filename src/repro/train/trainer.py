@@ -13,7 +13,10 @@ Production behaviours, testable single-host:
 Each part of a step (batch, dispatch, sync) and each checkpoint save is a
 ``jax.profiler.TraceAnnotation`` named ``repro.train.*`` with the step as an
 argument, so a profiler trace of a running trainer places them beside the
-device's work (README, "Tracing").
+device's work (README, "Tracing"). A model with experts fetches each
+step's routed rows with its loss and writes them in ``repro.train.moe``
+after the sync: ``tokens`` of the batch, ``rows`` summed over the MoE blocks
+and ``rows_max``, the busiest held expert of any block.
 """
 from __future__ import annotations
 
@@ -135,7 +138,16 @@ class Trainer:
                     span.set_metadata(attempt=attempt)
                 self.step += 1
                 with TraceAnnotation("repro.train.sync", step=self.step - 1):
-                    loss = float(metrics["loss"])     # waits for the step
+                    # waits for the step
+                    loss, rows = jax.device_get(
+                        (metrics["loss"], metrics.get("expert_rows")))
+                    loss = float(loss)
+                if rows is not None:
+                    with TraceAnnotation(
+                            "repro.train.moe", step=self.step - 1,
+                            tokens=int(batch["tokens"].size),
+                            rows=int(rows.sum()), rows_max=int(rows.max())):
+                        pass
                 losses.append(loss)
                 step_s.append(time.monotonic() - t_step)
                 if self.step % self.tc.log_every == 0:

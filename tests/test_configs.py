@@ -17,7 +17,7 @@ EXPECT = {
     "xlstm_1_3b":         (48, 2048, 4, 4, 0, 50304),
     "zamba2_2_7b":        (54, 2560, 32, 32, 10240, 32000),
     "mixtral_8x22b":      (56, 6144, 48, 8, 16384, 32768),
-    "moonshot_v1_16b_a3b": (48, 2048, 16, 16, 1408, 163840),
+    "moonshot_v1_16b_a3b": (27, 2048, 16, 16, 1408, 163840),
     "qwen2_vl_7b":        (28, 3584, 28, 4, 18944, 152064),
     "whisper_small":      (12, 768, 12, 12, 3072, 51865),
 }
@@ -27,9 +27,9 @@ EXPECT = {
 #  - xlstm_1_3b: the assignment's 48L x d2048 with ssm_expand=2 and explicit
 #    q/k/v projections lands at 3.0B; the paper's exact 1.3B projection
 #    layout is not public ([arXiv:2405.04517; unverified] tier).
-#  - moonshot_v1_16b_a3b: the assignment's 48L x 64 experts x d_ff 1408 is
-#    26.5B of expert weights alone (the hf 16B model uses 27 layers); the
-#    ACTIVE count (~4B) matches the "a3b" nameplate to within formulation.
+#  - moonshot_v1_16b_a3b: the published Moonlight-16B-A3B config.json: 27
+#    layers (one dense, then 26 of 64 routed experts x d_ff 1408 beside 2
+#    shared), latent attention; 15.96B total, ~2.9B active a token.
 PARAM_BAND = {
     "qwen3_14b":          (12e9, 17e9),
     "nemotron_4_340b":    (280e9, 400e9),
@@ -38,7 +38,7 @@ PARAM_BAND = {
     "xlstm_1_3b":         (1.0e9, 3.3e9),
     "zamba2_2_7b":        (2.2e9, 3.3e9),
     "mixtral_8x22b":      (115e9, 160e9),
-    "moonshot_v1_16b_a3b": (13e9, 30e9),
+    "moonshot_v1_16b_a3b": (15e9, 17e9),
     "qwen2_vl_7b":        (6e9, 9e9),
     "whisper_small":      (0.2e9, 0.35e9),
 }

@@ -139,3 +139,30 @@ def test_compression_ratio():
     raw = x.size * 4
     compressed = q.size * 1 + scale.size * 4
     assert compressed < raw / 3.5                     # ~4x minus scale overhead
+
+
+def test_weight_decay_leaves_vectors_alone_by_name():
+    """Every leaf that is a vector per layer (norm gains, biases, the MoE
+    selection bias, SSM parameters) is named in NO_DECAY; no matrix is."""
+    from repro.configs import ARCH_IDS, get_config
+    from repro.models import abstract_params
+    from repro.optim.adamw import NO_DECAY, leaf_name
+    for arch in ARCH_IDS:
+        cfg = get_config(arch).reduced()
+        flat = jax.tree_util.tree_flatten_with_path(abstract_params(cfg))[0]
+        for path, leaf in flat:
+            keys = [str(getattr(k, "key", k)) for k in path]
+            stacked = "stages" in keys or "encoder" in keys
+            vector = leaf.ndim - stacked < 2
+            assert (leaf_name(path) in NO_DECAY) == vector, (arch, keys)
+
+
+def test_stacked_norm_gains_are_not_decayed():
+    p = {"stages": [{"ln1": jnp.ones((3, 4)), "e_bias": jnp.ones((3, 8)),
+                     "wq": jnp.ones((3, 4, 4))}]}
+    g = jax.tree_util.tree_map(jnp.zeros_like, p)
+    newp, _, _ = adamw_update(g, adamw_init(p), p, lr=0.1, weight_decay=0.5)
+    st = newp["stages"][0]
+    assert float(jnp.max(jnp.abs(st["ln1"] - 1.0))) < 1e-7
+    assert float(jnp.max(jnp.abs(st["e_bias"] - 1.0))) < 1e-7
+    assert float(jnp.max(st["wq"])) < 1.0

@@ -108,7 +108,11 @@ def test_insert_program_is_named():
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_init_cache_leaves_are_distinct_buffers(arch):
     """Donating the cache would fail on a buffer that two leaves share."""
-    leaves = jax.tree_util.tree_leaves(
-        init_cache(get_config(arch).reduced(), 4, 32))
+    cfg = get_config(arch).reduced()
+    if cfg.mla:
+        with pytest.raises(NotImplementedError, match="latent cache"):
+            init_cache(cfg, 4, 32)
+        return
+    leaves = jax.tree_util.tree_leaves(init_cache(cfg, 4, 32))
     ptrs = {x.unsafe_buffer_pointer() for x in leaves}
     assert len(ptrs) == len(leaves)
